@@ -65,7 +65,6 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     _force_host_devices(args.simulate_devices)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     import jax
     import numpy as np
@@ -74,7 +73,7 @@ def main(argv=None):
     from bench_throughput import SERVING_TRACE, _serve_model
 
     from repro.kernels.ops import _default_interpret
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.runtime import ReplicatedServeLoop, Request
 
     sweep = sorted({int(x) for x in args.sweep.split(",")})
@@ -107,7 +106,7 @@ def main(argv=None):
 
     streams_by_n = {}
     for n in sweep:
-        mesh = make_mesh_compat((n, 1), ("data", "model"))
+        mesh = make_mesh((n, 1), ("data", "model"))
         loop = ReplicatedServeLoop(
             model, params, mesh=mesh,
             batch_slots=args.batch_slots,

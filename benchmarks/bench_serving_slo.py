@@ -284,7 +284,6 @@ def main(argv=None):
                     help="SLO: per-request p95 inter-token budget")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     record = run_serving_slo_bench(
         requests=args.requests, seed=args.seed, tenants=args.tenants,
         long_every=args.long_every, new_tokens=args.new_tokens,

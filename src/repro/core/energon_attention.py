@@ -512,10 +512,11 @@ def _fused_prefill_engaged(
     Mirrors :func:`_fused_decode_engaged` (resident planes, no window,
     2 rounds, Fig. 7 reuse) plus the prefill-only constraints: no
     padded-cache ``kv_length`` masking (the kernel masks by per-row
-    positions alone) and a cache length divisible into the resident
-    plane blocks the codes are scaled by. Callers additionally require
-    ``q_positions`` + ``causal`` — the kernel's on-chip mask is exactly
-    ``key_pos ≤ query_pos < n_k``.
+    positions alone), a cache length divisible into the resident plane
+    blocks the codes are scaled by, and key tiles made of whole plane
+    blocks (the kernels stream one scale block — one page — per step).
+    Callers additionally require ``q_positions`` + ``causal`` — the
+    kernel's on-chip mask is exactly ``key_pos ≤ query_pos < n_k``.
     """
     return (
         cfg.impl == "pallas"
@@ -526,6 +527,7 @@ def _fused_prefill_engaged(
         and cfg.reuse_partial
         and cfg.decode_key_block > 0
         and n_k % cfg.decode_key_block == 0
+        and cfg.key_block % cfg.decode_key_block == 0
     )
 
 
@@ -545,7 +547,7 @@ def energon_paged_prefill_attention(
 
     The paged counterpart of the ``q_positions`` form of
     :func:`energon_attention`. When the fused prefill kernels engage
-    (resident planes, page size == key tile, block-divisible shapes),
+    (resident planes, key tiles of whole pages, block-divisible shapes),
     the chunk attends the pool *in place*: the filter kernel scores the
     per-page codes through the block table and the gather kernel's
     BlockSpec index maps compose survivor table ∘ block table, so
@@ -577,10 +579,7 @@ def energon_paged_prefill_attention(
         layer_index >= cfg.min_prune_layer
         and _fused_prefill_engaged(cfg, "k_codes" in cache, window,
                                    None, n_k)
-        # the paged kernels address one page per key tile: the survivor
-        # ∘ block-table index composition only lines up when the two
-        # granularities coincide
-        and cfg.key_block == ps
+        and n_k % cfg.key_block == 0
         and n_q % cfg.query_block == 0
         and n_k // cfg.key_block > 1
     )

@@ -21,7 +21,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map_unchecked as shard_map
 
 INT8_MAX = 127.0
 
@@ -87,8 +86,9 @@ def compressed_psum_shard_map(
 
     specs_g = jax.tree.map(lambda _: P(), grads)
     specs_e = jax.tree.map(lambda _: P(), err_state)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(specs_g, specs_e),
         out_specs=(specs_g, specs_e),
+        check_vma=False,
     )(grads, err_state)

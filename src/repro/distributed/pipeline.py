@@ -21,7 +21,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map_unchecked as shard_map
 
 
 def pipeline_forward(
@@ -91,11 +90,12 @@ def pipeline_forward(
         return jax.lax.psum(outputs * is_last, axis)
 
     in_spec = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(in_spec, P()),
         out_specs=P(),
+        check_vma=False,
     )(stage_params, microbatches)
 
 

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map_unchecked
 from repro.core import filtering as flt
 from repro.core import quantization as qlib
 from repro.core import sparse_attention as spa
@@ -237,46 +236,42 @@ def fused_decode_attention(
     if len(round_bits) != 2:
         raise ValueError("fused decode kernel supports 2-round configs")
     interpret = _default_interpret() if interpret is None else interpret
-    batch, heads, g, d = q.shape
-    n_k = k_cache.shape[-2]
+    batch, heads, _, d = q.shape
     bk = key_block
-    n_kb = n_k // bk
-    bh = batch * heads
-
-    q16 = qlib.quantize_int16(q, axis=-1)
-    qp = q16.bit_plane(round_bits[-1]).reshape(bh, g, d)
-    qs = q16.scale.reshape(bh, g, 1)
-    cl_bh = jnp.repeat(cache_length.astype(jnp.int32), heads)
-
-    s0, s1 = dec_kernel.mpmrf_decode_filter_scores(
-        qp, qs,
-        k_codes.reshape(bh, n_k, d),
-        k_block_scale.reshape(bh, n_kb),
-        cl_bh,
-        round_bits=tuple(round_bits),
-        key_block=bk,
-        interpret=interpret,
+    n_kb = k_cache.shape[-2] // bk
+    n_pages = batch * heads * n_kb
+    out, stats = _decode_pipeline(
+        q,
+        k_cache.reshape(n_pages, bk, d),
+        v_cache.reshape(n_pages, bk, d),
+        k_codes.reshape(n_pages, bk, d),
+        k_block_scale.reshape(n_pages, 1),
+        _identity_table(batch * heads, n_kb),
+        cache_length, live_budget,
+        round_bits=tuple(round_bits), alphas=tuple(alphas),
+        key_block=bk, block_budget=block_budget, keep_all=keep_all,
+        keep_first=keep_first, keep_diagonal=keep_diagonal, scale=scale,
+        interpret=interpret, with_stats=telemetry,
     )
+    return (out, stats) if telemetry else out
 
-    idx, val, stats = _fused_decode_select(
-        s0, s1, cl_bh,
-        alphas=alphas, key_block=bk, block_budget=block_budget,
-        keep_all=keep_all, keep_first=keep_first,
-        keep_diagonal=keep_diagonal,
-        live_budget=live_budget, heads=heads, with_stats=telemetry,
-    )
 
-    out = dec_kernel.decode_gather_attention(
-        q.reshape(bh, g, d),
-        k_cache.reshape(bh, n_k, d),
-        v_cache.reshape(bh, n_k, d),
-        idx, val, cl_bh,
-        key_block=bk, scale=scale, interpret=interpret,
-    )
-    out = out.reshape(batch, heads, g, d)
-    if telemetry:
-        return out, stats.reshape(batch, heads, 4).sum(axis=1)
-    return out
+def _identity_table(rows: int, blocks: int) -> jax.Array:
+    """Block table of an unpaged cache viewed as pages: row r's logical
+    block j is page ``r · blocks + j`` of the cache reshaped to
+    ``[rows · blocks, bk, d]`` (a free reshape — no copy)."""
+    return jnp.arange(rows * blocks, dtype=jnp.int32).reshape(rows, blocks)
+
+
+def _head_offset_table(block_table: jax.Array, heads: int,
+                       num_pages: int) -> jax.Array:
+    """``[B, mb]`` slot tables → ``[B·KV, mb]`` rows into pools whose
+    KV-head axis is folded into the page axis ([KV, P, ...] →
+    [KV·P, ...]): row b·KV+h points at head h's copy of the slot's
+    pages."""
+    head_off = jnp.arange(heads, dtype=jnp.int32) * num_pages
+    bt = block_table.astype(jnp.int32)[:, None, :] + head_off[None, :, None]
+    return bt.reshape(-1, block_table.shape[-1])
 
 
 def _fused_decode_select(
@@ -436,65 +431,72 @@ def fused_paged_decode_attention(
             stats = jax.lax.psum(stats, "model")
         return (out, stats) if telemetry else out
 
-    fn = shard_map_unchecked(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=tuple(specs),
         out_specs=(P(), P()) if telemetry else P(),
+        check_vma=False,
     )
     return fn(*args)
 
 
 def _paged_decode_core(
     q, k_pool, v_pool, k_codes, k_scale, block_table, cache_length,
-    live_budget, *, round_bits, alphas, key_block, block_budget,
-    keep_all, keep_first, keep_diagonal, scale, interpret, with_stats,
+    live_budget, **kw,
 ):
     """Shard-local fused paged decode: the pipeline of
     :func:`fused_paged_decode_attention` over whatever KV-head slice of
     the pools the caller holds (the full pools on a single device).
     Returns ``(out, stats_or_None)``."""
+    heads, d = q.shape[1], q.shape[-1]
+    bk = kw["key_block"]
+    num_pages = k_scale.shape[-1]
+    n_pages = heads * num_pages
+    return _decode_pipeline(
+        q,
+        k_pool.reshape(n_pages, bk, d),
+        v_pool.reshape(n_pages, bk, d),
+        k_codes.reshape(n_pages, bk, d),
+        k_scale.reshape(n_pages, 1),
+        _head_offset_table(block_table, heads, num_pages),
+        cache_length, live_budget, **kw,
+    )
+
+
+def _decode_pipeline(
+    q, k_pages, v_pages, codes_pages, scale_pages, bt_bh, cache_length,
+    live_budget, *, round_bits, alphas, key_block, block_budget,
+    keep_all, keep_first, keep_diagonal, scale, interpret, with_stats,
+):
+    """Filter kernel → shared selection → gather kernel over page-major
+    operands ``[n_pages, bk, d]`` addressed by the per-(batch, head)
+    table ``bt_bh [B·H, mb]``. Returns ``(out, stats_or_None)``."""
     batch, heads, g, d = q.shape
-    pool_rows = k_pool.shape[-2]
-    bk = key_block
-    num_pages = pool_rows // bk
-    mb = block_table.shape[-1]
     bh = batch * heads
 
     q16 = qlib.quantize_int16(q, axis=-1)
     qp = q16.bit_plane(round_bits[-1]).reshape(bh, g, d)
     qs = q16.scale.reshape(bh, g, 1)
     cl_bh = jnp.repeat(cache_length.astype(jnp.int32), heads)
-    # Head-offset physical table: the pools fold the KV-head axis into
-    # the page axis ([KV, P, ...] → [KV·P, ...]), so row b·KV+h of the
-    # table points at head h's copy of the slot's pages.
-    head_off = (jnp.arange(heads, dtype=jnp.int32) * num_pages)
-    bt_bh = (
-        block_table.astype(jnp.int32)[:, None, :] + head_off[None, :, None]
-    ).reshape(bh, mb)
 
     s0, s1 = dec_kernel.mpmrf_paged_filter_scores(
-        qp, qs,
-        k_codes.reshape(heads * num_pages, bk, d),
-        k_scale.reshape(heads * num_pages, 1),
-        bt_bh, cl_bh,
+        qp, qs, codes_pages, scale_pages, bt_bh, cl_bh,
         round_bits=tuple(round_bits),
-        key_block=bk,
+        key_block=key_block,
         interpret=interpret,
     )
 
     idx, val, stats = _fused_decode_select(
         s0, s1, cl_bh,
-        alphas=alphas, key_block=bk, block_budget=block_budget,
+        alphas=alphas, key_block=key_block, block_budget=block_budget,
         keep_all=keep_all, keep_first=keep_first,
         keep_diagonal=keep_diagonal,
         live_budget=live_budget, heads=heads, with_stats=with_stats,
     )
 
     out = dec_kernel.paged_decode_gather_attention(
-        q.reshape(bh, g, d),
-        k_pool.reshape(heads * num_pages, bk, d),
-        v_pool.reshape(heads * num_pages, bk, d),
+        q.reshape(bh, g, d), k_pages, v_pages,
         idx, val, bt_bh, cl_bh,
-        key_block=bk, scale=scale, interpret=interpret,
+        key_block=key_block, scale=scale, interpret=interpret,
     )
     out = out.reshape(batch, heads, g, d)
     if with_stats:
@@ -611,55 +613,25 @@ def fused_prefill_attention(
     if len(round_bits) != 2:
         raise ValueError("fused prefill kernel supports 2-round configs")
     interpret = _default_interpret() if interpret is None else interpret
-    batch, heads, n_q, d = q.shape
-    n_k = k_cache.shape[-2]
-    if n_k % filter_block:
-        raise ValueError(
-            f"cache rows {n_k} not divisible by filter block {filter_block}"
-        )
-    bh = batch * heads
-
-    q16 = qlib.quantize_int16(q, axis=-1)
-    qp = q16.bit_plane(round_bits[-1]).reshape(bh, n_q, d)
-    qs = q16.scale.reshape(bh, n_q, 1)
-    qpos_bh = jnp.repeat(q_positions.astype(jnp.int32), heads, axis=0)
-    # Per-row dequantization scales: the exact expansion
-    # blockwise_quantized_view performs for the XLA path.
-    ks_row = jnp.repeat(
-        k_block_scale.astype(jnp.float32), filter_block, axis=-1
-    ).reshape(bh, n_k)
-
-    s0, s1 = pre_kernel.mpmrf_prefill_filter_scores(
-        qp, qs, qpos_bh,
-        k_codes.reshape(bh, n_k, d),
-        ks_row,
-        round_bits=tuple(round_bits),
-        query_block=query_block,
-        key_block=key_block,
-        interpret=interpret,
-    )
-
-    idx, val, stats = _fused_prefill_select(
-        s0, s1,
-        round_bits=round_bits, alphas=alphas,
+    batch, heads, _, d = q.shape
+    fb = filter_block
+    n_fb = k_cache.shape[-2] // fb
+    n_pages = batch * heads * n_fb
+    out, stats = _prefill_pipeline(
+        q,
+        k_cache.reshape(n_pages, fb, d),
+        v_cache.reshape(n_pages, fb, d),
+        k_codes.reshape(n_pages, fb, d),
+        k_block_scale.reshape(n_pages, 1),
+        _identity_table(batch * heads, n_fb),
+        q_positions, diag_blocks,
+        round_bits=tuple(round_bits), alphas=tuple(alphas),
         query_block=query_block, key_block=key_block,
         block_budget=block_budget, keep_all=keep_all,
         keep_first=keep_first, keep_diagonal=keep_diagonal,
-        diag_blocks=diag_blocks, heads=heads, with_stats=telemetry,
+        scale=scale, interpret=interpret, with_stats=telemetry,
     )
-
-    out = pre_kernel.prefill_gather_attention(
-        q.reshape(bh, n_q, d), qpos_bh,
-        k_cache.reshape(bh, n_k, d),
-        v_cache.reshape(bh, n_k, d),
-        idx, val,
-        query_block=query_block, key_block=key_block,
-        scale=scale, interpret=interpret,
-    )
-    out = out.reshape(batch, heads, n_q, d)
-    if telemetry:
-        return out, stats.reshape(batch, heads, 4).sum(axis=1)
-    return out
+    return (out, stats) if telemetry else out
 
 
 def fused_paged_prefill_attention(
@@ -692,8 +664,9 @@ def fused_paged_prefill_attention(
     table, and the gather kernel composes the survivor table with the
     block table inside its index maps (selected logical block →
     physical page → stream K/V), so unselected *and unmapped* pages
-    never leave HBM. Requires page size == ``key_block`` (the logical
-    key blocks of prefill selection are the pool's pages).
+    never leave HBM. ``key_block`` (the selection granularity) must be
+    a whole number of pages; the page size is the pools' rows per
+    ``k_scale`` entry.
 
     Args:
       q: ``[B, KV, n_q, d]`` folded chunk rows.
@@ -758,66 +731,96 @@ def fused_paged_prefill_attention(
             stats = jax.lax.psum(stats, "model")
         return (out, stats) if telemetry else out
 
-    fn = shard_map_unchecked(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=tuple(specs),
         out_specs=(P(), P()) if telemetry else P(),
+        check_vma=False,
     )
     return fn(*args)
 
 
 def _paged_prefill_core(
     q, k_pool, v_pool, k_codes, k_scale, block_table, q_positions,
-    diag_blocks, *, round_bits, alphas, query_block, key_block,
-    block_budget, keep_all, keep_first, keep_diagonal, scale,
-    interpret, with_stats,
+    diag_blocks, **kw,
 ):
     """Shard-local fused paged prefill: the pipeline of
     :func:`fused_paged_prefill_attention` over whatever KV-head slice
     of the pools the caller holds. Returns ``(out, stats_or_None)``."""
+    heads, d = q.shape[1], q.shape[-1]
+    num_pages = k_scale.shape[-1]
+    page = k_pool.shape[-2] // num_pages
+    n_pages = heads * num_pages
+    return _prefill_pipeline(
+        q,
+        k_pool.reshape(n_pages, page, d),
+        v_pool.reshape(n_pages, page, d),
+        k_codes.reshape(n_pages, page, d),
+        k_scale.reshape(n_pages, 1),
+        _head_offset_table(block_table, heads, num_pages),
+        q_positions, diag_blocks, **kw,
+    )
+
+
+def _prefill_pipeline(
+    q, k_pages, v_pages, codes_pages, scale_pages, bt_bh, q_positions,
+    diag_blocks, *, round_bits, alphas, query_block, key_block,
+    block_budget, keep_all, keep_first, keep_diagonal, scale,
+    interpret, with_stats,
+):
+    """Filter kernel → shared selection → gather kernel over page-major
+    operands ``[n_pages, page, d]`` addressed by ``bt_bh [B·H, mb]``.
+
+    Selection runs at ``key_block`` granularity, a whole number ``r``
+    of pages: the kernels work per page, the filter's page maxima are
+    max-pooled r at a time (max of maxima — exact), and each selected
+    key block expands to its r pages for the gather. Returns
+    ``(out, stats_or_None)``."""
     batch, heads, n_q, d = q.shape
-    pool_rows = k_pool.shape[-2]
-    bk = key_block
-    num_pages = pool_rows // bk
-    mb = block_table.shape[-1]
+    page = k_pages.shape[-2]
+    if key_block % page:
+        raise ValueError(
+            f"key block {key_block} is not a whole number of {page}-row pages"
+        )
+    r = key_block // page
+    mb = bt_bh.shape[-1]
+    if mb % r:
+        raise ValueError(f"{mb} pages do not tile {key_block}-row key blocks")
     bh = batch * heads
 
     q16 = qlib.quantize_int16(q, axis=-1)
     qp = q16.bit_plane(round_bits[-1]).reshape(bh, n_q, d)
     qs = q16.scale.reshape(bh, n_q, 1)
     qpos_bh = jnp.repeat(q_positions.astype(jnp.int32), heads, axis=0)
-    # Head-offset physical table (pools fold the KV-head axis into the
-    # page axis), exactly as the fused paged decode path.
-    head_off = (jnp.arange(heads, dtype=jnp.int32) * num_pages)
-    bt_bh = (
-        block_table.astype(jnp.int32)[:, None, :] + head_off[None, :, None]
-    ).reshape(bh, mb)
 
     s0, s1 = pre_kernel.mpmrf_paged_prefill_filter_scores(
-        qp, qs, qpos_bh,
-        k_codes.reshape(heads * num_pages, bk, d),
-        k_scale.reshape(heads * num_pages, 1),
-        bt_bh,
+        qp, qs, qpos_bh, codes_pages, scale_pages, bt_bh,
         round_bits=tuple(round_bits),
         query_block=query_block,
-        key_block=bk,
+        key_block=page,
         interpret=interpret,
     )
+    if r > 1:
+        n_qb = n_q // query_block
+        s0 = jnp.max(s0.reshape(bh, n_qb, mb // r, r), axis=-1)
+        s1 = jnp.max(s1.reshape(bh, n_qb, mb // r, r), axis=-1)
 
     idx, val, stats = _fused_prefill_select(
         s0, s1,
         round_bits=round_bits, alphas=alphas,
-        query_block=query_block, key_block=bk,
+        query_block=query_block, key_block=key_block,
         block_budget=block_budget, keep_all=keep_all,
         keep_first=keep_first, keep_diagonal=keep_diagonal,
         diag_blocks=diag_blocks, heads=heads, with_stats=with_stats,
     )
+    if r > 1:
+        idx = (idx[..., None] * r + jnp.arange(r, dtype=idx.dtype))
+        idx = idx.reshape(*idx.shape[:-2], -1)
+        val = jnp.repeat(val, r, axis=-1)
 
     out = pre_kernel.paged_prefill_gather_attention(
-        q.reshape(bh, n_q, d), qpos_bh,
-        k_pool.reshape(heads * num_pages, bk, d),
-        v_pool.reshape(heads * num_pages, bk, d),
+        q.reshape(bh, n_q, d), qpos_bh, k_pages, v_pages,
         idx, val, bt_bh,
-        query_block=query_block, key_block=bk,
+        query_block=query_block, key_block=page,
         scale=scale, interpret=interpret,
     )
     out = out.reshape(batch, heads, n_q, d)

@@ -1,12 +1,16 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST run before any other import (jax locks the
-device count at first backend init): the dry-run — and only the
-dry-run — sees 512 placeholder CPU devices so `make_production_mesh`
-can build the 16×16 single-pod and 2×16×16 multi-pod meshes.
+The lines above MUST run before any other import (jax locks the
+platform and device count at first backend init): the dry-run — and
+only the dry-run — sees 512 placeholder CPU devices so
+`make_production_mesh` can build the 16×16 single-pod and 2×16×16
+multi-pod meshes. It is pinned to the CPU so that on a TPU host it
+neither holds the chip (its ``--all`` mode spawns one child per cell)
+nor meets a mesh larger than the host's chips.
 
 Per cell this produces an artifact JSON under ``artifacts/dryrun/`` with
 ``memory_analysis`` / ``cost_analysis`` outputs plus the loop-aware

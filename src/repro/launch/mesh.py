@@ -10,16 +10,12 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer releases; older ones
-    default to auto axes anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with auto-sharded axes (its default is explicit
+    axes, which the GSPMD sharding rules here do not use)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,11 +28,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Tiny mesh over whatever devices the host actually has (tests)."""
     n = len(jax.devices())
     data = n // model_axis
-    return make_mesh_compat((data, model_axis), ("data", "model"))
+    return make_mesh((data, model_axis), ("data", "model"))

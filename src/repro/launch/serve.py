@@ -116,16 +116,18 @@ def main():
     import numpy as np
 
     from repro.configs.registry import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import LMModel
     from repro.runtime import (
         FaultInjector, FaultSpec, QueueFull, ReplicatedServeLoop, Request,
         ServeLoop, attention_cache_bytes,
     )
 
+    enable_compile_cache()
     mesh = None
     mesh_shape = None
     if args.mesh is not None:
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
 
         mesh_shape = _parse_mesh(args.mesh)
         need = mesh_shape[0] * mesh_shape[1]
@@ -135,7 +137,7 @@ def main():
                 f"--mesh {args.mesh} needs {need} devices, have {have} "
                 f"(try --simulate-devices {need})"
             )
-        mesh = make_mesh_compat(mesh_shape, ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = LMModel(cfg)

@@ -44,11 +44,13 @@ def main():
 
     from repro.configs.registry import get_config, get_smoke_config
     from repro.data import TokenDataset
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import LMModel
     from repro.optim import AdamWConfig, warmup_cosine
     from repro.runtime import TrainConfig, TrainLoop
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = LMModel(cfg)
     mesh = make_host_mesh(args.model_axis) if len(jax.devices()) > 1 else None

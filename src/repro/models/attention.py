@@ -284,8 +284,15 @@ def _project_update_fold(
 def _unfold_heads_out(
     out: jax.Array, params, num_heads: int, chunk: int
 ) -> jax.Array:
-    """``[B, KV, G·C, hd]`` attention output → ``[B, C, d_model]``."""
+    """``[B, KV, G·C, hd]`` attention output → ``[B, C, d_model]``.
+
+    Under a mesh the heads are gathered first: a head-sharded output
+    would let the partitioner split the projection's contraction over
+    devices and all-reduce bf16 partial sums, which rounds differently
+    from the one-device contraction (the serve-TP bit-identity
+    contract, DESIGN.md §9)."""
     batch, _, _, head_dim = out.shape
+    out = shd.constrain(out, ("dp", None, None, None))
     out = out.reshape(batch, num_heads, chunk, head_dim)
     out = out.transpose(0, 2, 1, 3)          # [B, C, H, hd]
     return jnp.einsum("bnhk,hkd->bnd", out, params["wo"])

@@ -163,7 +163,6 @@ def _sharded_embed_lookup(table: jax.Array, tokens: jax.Array, mesh):
     psum — the standard TP embedding pattern. XLA's auto-partitioner
     cannot do this for us (it replicates the table, or worse).
     """
-    from repro.compat import shard_map_unchecked as shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import sharding as shd
@@ -187,10 +186,10 @@ def _sharded_embed_lookup(table: jax.Array, tokens: jax.Array, mesh):
 
     token_spec = P(batch_axis, *([None] * (tokens.ndim - 1)))
     out_spec = P(batch_axis, *([None] * tokens.ndim))
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("model", None), token_spec),
-        out_specs=out_spec,
+        out_specs=out_spec, check_vma=False,
     )(table, tokens)
 
 

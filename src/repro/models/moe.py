@@ -205,7 +205,6 @@ def _apply_moe_sharded(
     weight all-gather over 'data' (ZeRO) + one activation psum over
     'model' — nothing else.
     """
-    from repro.compat import shard_map_unchecked as shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import sharding as shd
@@ -312,12 +311,13 @@ def _apply_moe_sharded(
     else:
         up_spec = P("model", "data" if zero_sharded else None, None)
         down_spec = P("model", None, "data" if zero_sharded else None)
-    out, aux, drop = shard_map(
+    out, aux, drop = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(P(), up_spec,
                   up_spec if has_gate else P(), down_spec, x_spec),
         out_specs=(x_spec, P(), P()),
+        check_vma=False,
     )(
         params["router"],
         params["w_up"],
@@ -341,7 +341,6 @@ def _apply_moe_serve_2d(
     per layer: one token all-gather (≤1 MB) + one output psum (≤2 MB) —
     versus ~300 MB of weight gathers in the training layout.
     """
-    from repro.compat import shard_map_unchecked as shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import sharding as shd
@@ -426,12 +425,13 @@ def _apply_moe_serve_2d(
     x_spec = P(batch_axis, None, None)
     up_spec = P("model", None, "data")
     down_spec = P("model", "data", None)
-    out, aux, drop = shard_map(
+    out, aux, drop = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(P(), up_spec, up_spec if has_gate else P(), down_spec,
                   x_spec),
         out_specs=(x_spec, P(), P()),
+        check_vma=False,
     )(
         params["router"], params["w_up"],
         params.get("w_gate", params["router"]), params["w_down"], x,

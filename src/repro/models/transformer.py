@@ -105,26 +105,6 @@ def _tree_slice(tree, lo: int, hi: Optional[int]):
     return jax.tree.map(lambda a: a[lo:hi], tree)
 
 
-@jax.custom_vjp
-def _barrier(x):
-    """Differentiable ``optimization_barrier`` (this jax version ships no
-    autodiff rule for the primitive). The backward pass re-applies the
-    barrier to the cotangent so the residual convert stays pinned inside
-    the backward loop body too."""
-    return jax.lax.optimization_barrier(x)
-
-
-def _barrier_fwd(x):
-    return _barrier(x), None
-
-
-def _barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-_barrier.defvjp(_barrier_fwd, _barrier_bwd)
-
-
 def _scan_factors(n: int) -> Tuple[int, int]:
     """(outer, inner) factorization minimizing outer+inner (≈2√n).
 
@@ -177,7 +157,7 @@ def apply_stack(
             # residual buffer to f32 outside the backward loop — an
             # L × activation-size f32 copy (11.8 GB/chip on the 94-layer
             # MoE). The barrier pins the convert inside the loop body.
-            x = _barrier(x)
+            x = jax.lax.optimization_barrier(x)
             layer_params, window = xs
             fn = block_fn
             if remat == "full":
@@ -255,65 +235,59 @@ def apply_stack_decode(
     ``step_fn(params, x, cache, window, layer_idx) -> (x, new_cache)``
     with ``layer_idx`` static (see :func:`apply_stack`). With
     ``telemetry`` the step returns ``(x, new_cache, stats [B, 4])``;
-    per-layer stats ride the scan's stacked outputs alongside the
-    caches and come back as int32 ``[L, B, 4]``.
+    per-layer stats ride the scan's stacked outputs and come back as
+    int32 ``[L, B, 4]``.
+
+    The scans run over layer *indices*: each step reads its layer's
+    weights and cache in place from the stacked arrays and writes the
+    cache back into the carried stack. Slicing the stacks per scan
+    (prefix / rest) would copy every weight and cache leaf outside the
+    loop — at full width that doubles the weights' footprint and does
+    not fit the device.
     """
     num_layers = jax.tree_util.tree_leaves(params_stacked)[0].shape[0]
     if windows is None:
         windows = jnp.zeros((num_layers,), jnp.int32)
     prefix_layers = min(prefix_layers, num_layers)
 
+    def layer(tree, i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            tree,
+        )
+
     def make_body(static_layer_idx: int):
-        def body(x, xs):
-            layer_params, cache, window = xs
-            if telemetry:
-                x, new_cache, stats = step_fn(
-                    layer_params, x, cache, window, static_layer_idx
-                )
-                return shd.constrain(x, ("dp", None, None)), (new_cache, stats)
-            x, new_cache = step_fn(
-                layer_params, x, cache, window, static_layer_idx
+        def body(carry, xs):
+            x, caches = carry
+            i, window = xs
+            out = step_fn(
+                layer(params_stacked, i), x, layer(caches, i), window,
+                static_layer_idx,
             )
-            return shd.constrain(x, ("dp", None, None)), new_cache
+            x, new_cache = out[0], out[1]
+            caches = jax.tree.map(
+                lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                    a, u.astype(a.dtype), i, 0
+                ),
+                caches, new_cache,
+            )
+            x = shd.constrain(x, ("dp", None, None))
+            return (x, caches), (out[2] if telemetry else None)
 
         return body
 
-    new_caches = []
     stats_parts = []
-
-    def collect(ys):
-        if telemetry:
-            nc, st = ys
-            stats_parts.append(st)
-            return nc
-        return ys
-
-    if prefix_layers > 0:
-        x, ys = jax.lax.scan(
-            make_body(0), x,
-            (_tree_slice(params_stacked, 0, prefix_layers),
-             _tree_slice(caches, 0, prefix_layers),
-             windows[:prefix_layers]),
-        )
-        new_caches.append(collect(ys))
-    if prefix_layers < num_layers:
-        x, ys = jax.lax.scan(
-            make_body(prefix_layers), x,
-            (_tree_slice(params_stacked, prefix_layers, None),
-             _tree_slice(caches, prefix_layers, None),
-             windows[prefix_layers:]),
-        )
-        new_caches.append(collect(ys))
-    if len(new_caches) == 1:
-        merged = new_caches[0]
-    else:
-        merged = jax.tree.map(
-            lambda a, b: jnp.concatenate([a, b], axis=0), *new_caches
-        )
+    for lo, hi in ((0, prefix_layers), (prefix_layers, num_layers)):
+        if lo < hi:
+            (x, caches), stats = jax.lax.scan(
+                make_body(lo), (x, caches),
+                (jnp.arange(lo, hi), windows[lo:hi]),
+            )
+            stats_parts.append(stats)
     if telemetry:
         stats = (
             stats_parts[0] if len(stats_parts) == 1
             else jnp.concatenate(stats_parts, axis=0)
         )
-        return x, merged, stats
-    return x, merged
+        return x, caches, stats
+    return x, caches

@@ -86,6 +86,14 @@ class SparsityAggregator:
         else:
             self._prefill_layers += per_layer
 
+    def layer_blocks(self, kind: str) -> Optional[np.ndarray]:
+        """Per-layer ``[L, 4]`` block totals (``STAT_FIELDS`` columns)
+        of every recorded ``kind`` ("decode" or "prefill") dispatch;
+        None before the first one."""
+        layers = {"decode": self._decode_layers,
+                  "prefill": self._prefill_layers}[kind]
+        return None if layers is None else layers.copy()
+
     # --- derived ratios ------------------------------------------------
 
     @staticmethod

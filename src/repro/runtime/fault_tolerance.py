@@ -1,8 +1,13 @@
 """Fault tolerance: shared serving + training resilience primitives.
 
 What a JAX SPMD job can and cannot do about failures:
-  * transient host/IO errors → bounded retry with exponential backoff
-    around the step call (`retry_step`, parameterized by `RetryPolicy`);
+  * transient failures raised *before* a step dispatches
+    (`TransientStepError`) → bounded retry with exponential backoff
+    around the step call (`retry_step`, parameterized by `RetryPolicy`).
+    Nothing else is retried by default: a device or compiler error
+    (JAX raises ``RuntimeError`` subclasses) surfaces after the step
+    has consumed its donated buffers, so a retry would run on freed
+    state;
   * node loss / preemption → the coordinator re-launches and the job
     auto-resumes from the newest valid checkpoint (see
     `repro.checkpoint`); SIGTERM triggers an immediate synchronous save
@@ -61,7 +66,7 @@ class RetryPolicy:
 
     max_retries: int = 3
     base_delay: float = 0.5
-    retriable: Tuple[type, ...] = (RuntimeError, OSError)
+    retriable: Tuple[type, ...] = (TransientStepError,)
 
 
 def retry_step(
@@ -70,7 +75,7 @@ def retry_step(
     policy: Optional[RetryPolicy] = None,
     max_retries: int = 3,
     base_delay: float = 0.5,
-    retriable=(RuntimeError, OSError),
+    retriable=(TransientStepError,),
     on_retry: Optional[Callable[[int, Exception], None]] = None,
 ):
     """Run ``fn(*args)`` with bounded exponential-backoff retries.

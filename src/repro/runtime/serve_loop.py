@@ -660,9 +660,22 @@ class ServeLoop:
                 max_blocks=mb, batch_slots=batch_slots,
             )
             self.allocator = PageAllocator(self.layout)
-            self.cache = model.init_paged_cache(
-                num_pages, max_len=self.max_len
-            )
+
+            def new_pool():
+                return model.init_paged_cache(num_pages, max_len=self.max_len)
+
+            if mesh is None:
+                self.cache = new_pool()
+            else:
+                # built in place on the mesh: a pool made on the default
+                # device first would have to fit there beside whatever
+                # that device already holds
+                self.cache = jax.jit(
+                    new_pool,
+                    out_shardings=shd.paged_cache_shardings(
+                        jax.eval_shape(new_pool), mesh, bk
+                    ),
+                )()
             self._reset_pages_fn = jax.jit(
                 model.reset_pages, donate_argnums=(0,)
             )

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -26,7 +26,7 @@ def run_subprocess(body: str) -> dict:
         import json
         import jax, jax.numpy as jnp
         import numpy as np
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         """
     ) + textwrap.dedent(body)
     env = dict(os.environ)
@@ -46,7 +46,7 @@ class TestShardingRules:
         from repro.distributed import sharding as shd
         from repro.models import LMModel
 
-        mesh = make_mesh_compat(
+        mesh = make_mesh(
             (1, 1), ("data", "model")
         )
         for arch in ARCH_NAMES:
@@ -58,7 +58,7 @@ class TestShardingRules:
     def test_divisibility_guard(self):
         from repro.distributed import sharding as shd
 
-        mesh = make_mesh_compat(
+        mesh = make_mesh(
             (1, 1), ("data", "model")
         )
 
@@ -79,7 +79,7 @@ class TestPipelineParallel:
         result = run_subprocess("""
         from repro.distributed.pipeline import (
             pipeline_forward, split_layers_to_stages)
-        mesh = make_mesh_compat((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         L, d = 8, 16
         ws = jax.random.normal(jax.random.PRNGKey(0), (L, d, d)) * d**-0.5
         def stage_fn(params, x):
@@ -98,7 +98,7 @@ class TestGradientCompression:
     def test_error_feedback_telescopes(self):
         result = run_subprocess("""
         from repro.distributed import compression as comp
-        mesh = make_mesh_compat((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (128,))
         acc = jnp.zeros_like(g); err = jnp.zeros_like(g)
         for _ in range(25):
@@ -149,7 +149,7 @@ class TestShardedTrainStep:
         }
         loss_ref = float(model.loss(params, batch)[0])
 
-        mesh = make_mesh_compat((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         shd.set_active_mesh(mesh)
         p_shard = shd.param_shardings(params, mesh)
         b_shard = shd.batch_shardings(batch, mesh)
@@ -168,7 +168,7 @@ class TestShardedTrainStep:
         result = run_subprocess("""
         from repro.models import moe as M
         from repro.distributed import sharding as shd
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = M.MoEConfig(num_experts=8, experts_per_token=2, d_model=32,
                           d_ff=16, capacity_factor=8.0)
         p = M.init_moe(jax.random.PRNGKey(0), cfg)
@@ -188,7 +188,7 @@ class TestElastic:
 
         params = {"w": np.random.default_rng(0).normal(size=(8, 4)).astype(
             np.float32)}
-        mesh = make_mesh_compat(
+        mesh = make_mesh(
             (1, 1), ("data", "model")
         )
         dev = elastic.reshard_params(params, mesh)
@@ -207,7 +207,7 @@ class TestPagedCacheSharding:
         from repro.distributed import sharding as shd
         from repro.models import LMModel
 
-        mesh = make_mesh_compat((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         cfg = ModelConfig(
             name="paged-shard", family="dense", num_layers=2, d_model=32,
             num_heads=4, num_kv_heads=2, head_dim=8, d_ff=64,
@@ -264,7 +264,7 @@ class TestPagedCacheSharding:
         from repro.distributed import sharding as shd
         from repro.models import LMModel
 
-        mesh = make_mesh_compat((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         cfg = ModelConfig(
             name="paged-shard-share", family="dense", num_layers=2,
             d_model=32, num_heads=4, num_kv_heads=2, head_dim=8, d_ff=64,
@@ -301,7 +301,7 @@ class TestPagedCacheSharding:
                                   query_block=8, key_block=16,
                                   decode_key_block=16, min_prune_layer=1))
         model = LMModel(cfg)
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         with mesh:
             shd.set_active_mesh(mesh)
             step = make_serve_step(model, mesh, num_pages=8)
@@ -342,7 +342,7 @@ class TestPagedCacheSharding:
                                   query_block=8, key_block=16,
                                   decode_key_block=16, min_prune_layer=1))
         model = LMModel(cfg)
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         with mesh:
             shd.set_active_mesh(mesh)
             step = make_serve_step(model, mesh, num_pages=8)
@@ -406,7 +406,7 @@ class TestFusedPrefillSharded:
             done = engine.run_until_drained()
             return {r.uid: list(r.tokens_out) for r in done}
 
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         with mesh:
             shd.set_active_mesh(mesh)
             fused = drain("pallas")
@@ -469,7 +469,7 @@ class TestMeshServeBitIdentity:
         result = run_subprocess(_SERVE_HELPERS + textwrap.dedent("""
         model, params = build("pallas")
         kw = dict(batch_slots=4, max_len=64, rng=jax.random.PRNGKey(7))
-        mesh = make_mesh_compat((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         ref = drain(ServeLoop(model, params, **kw))
         tp = drain(ServeLoop(model, params, mesh=mesh, **kw))
         ref_pre = drain(ServeLoop(model, params, num_pages=12, **kw))
@@ -487,6 +487,43 @@ class TestMeshServeBitIdentity:
         assert result["tp_preempt_eq_single_preempt"]
         assert result["preempted_eq_ample_on_mesh"]
         assert result["preemptions"] > 0  # the contract was exercised
+
+    def test_tp_steps_reduce_no_partial_sums(self):
+        """Weights are replicated, so no contraction may be split over
+        the model axis: an all-reduce of partial matmul sums rounds
+        differently from the one-device contraction (on a v5e in bf16
+        it flipped greedy tokens). The only all-reduce allowed is the
+        vocab-sharded embedding lookup's, which adds exact zeros."""
+        result = run_subprocess(_SERVE_HELPERS + textwrap.dedent("""
+        import re
+        from repro.distributed import sharding as shd
+        model, params = build("pallas")
+        mesh = make_mesh((1, 2), ("data", "model"))
+        e = ServeLoop(model, params, mesh=mesh, batch_slots=4,
+                      max_len=64, prefill_chunk=8)
+        table = e._device_block_table()
+        shd.set_active_mesh(mesh)
+        texts = [
+            e.step_fn.lower(e.params, e.cache, {
+                "tokens": jnp.zeros((4, 1), jnp.int32),
+                "active": jnp.zeros((4,), bool), "block_table": table,
+            }, e.cache_index).compile().as_text(),
+            e.prefill_fn.lower(e.params, e.cache, {
+                "tokens": jnp.zeros((4, 8), jnp.int32),
+                "positions": jnp.full((4, 8), e.max_len, jnp.int32),
+                "block_table": table,
+            }, e.cache_index).compile().as_text(),
+        ]
+        reduces = [l for t in texts for l in t.splitlines()
+                   if re.search(r" all-reduce(-start)?\\(", l)]
+        print(json.dumps({
+            "reduces": len(reduces),
+            "from_contractions": [l.strip()[:200] for l in reduces
+                                  if "dot_general" in l],
+        }))
+        """))
+        assert result["reduces"] > 0   # the embedding lookup's psum
+        assert result["from_contractions"] == []
 
     def test_shared_equals_unshared_on_tp_mesh(self):
         """Prefix sharing must stay invisible to outputs under the
@@ -516,7 +553,7 @@ class TestMeshServeBitIdentity:
         # pool evicts them before the next family member admits)
         kw = dict(batch_slots=2, max_len=64, prefill_chunk=8,
                   num_pages=32, rng=jax.random.PRNGKey(7))
-        mesh = make_mesh_compat((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         shared_eng = ServeLoop(model, params, mesh=mesh,
                                prefix_sharing=True, **kw)
         shared = drain2(shared_eng)
@@ -539,7 +576,7 @@ class TestMeshServeBitIdentity:
         bit-identity); ReplicatedServeLoop is the way to span it."""
         result = run_subprocess(_SERVE_HELPERS + textwrap.dedent("""
         model, params = build("mpmrf_block")
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         try:
             ServeLoop(model, params, batch_slots=2, max_len=64,
                       mesh=mesh)
@@ -564,11 +601,11 @@ class TestReplicatedServe:
         ref = drain(ServeLoop(model, params, **kw))
         r2 = ReplicatedServeLoop(
             model, params,
-            mesh=make_mesh_compat((2, 2), ("data", "model")), **kw)
+            mesh=make_mesh((2, 2), ("data", "model")), **kw)
         s2 = drain(r2)
         r4 = ReplicatedServeLoop(
             model, params,
-            mesh=make_mesh_compat((4, 1), ("data", "model")), **kw)
+            mesh=make_mesh((4, 1), ("data", "model")), **kw)
         s4 = drain(r4)
         print(json.dumps({
             "two_eq_single": s2 == ref,
@@ -593,7 +630,7 @@ class TestReplicatedServe:
         model, params = build("mpmrf_block")
         loop = ReplicatedServeLoop(
             model, params,
-            mesh=make_mesh_compat((2, 2), ("data", "model")),
+            mesh=make_mesh((2, 2), ("data", "model")),
             batch_slots=4, max_len=64, rng=jax.random.PRNGKey(7))
         drain(loop)
         m = loop.merged_metrics()

@@ -31,6 +31,11 @@ from repro.runtime import (
 )
 
 
+#: an EOS id no model can emit (sampled ids are ≥ 0): streams run to
+#: max_new_tokens whatever tokens the random weights produce
+NO_EOS = -1
+
+
 def _model(impl="mpmrf_block"):
     energon = (
         EnergonConfig(impl="dense") if impl == "dense"
@@ -86,7 +91,8 @@ def _drain(mt, trace, **kw):
     kw.setdefault("batch_slots", 3)
     kw.setdefault("max_len", 160)
     kw.setdefault("prefill_chunk", 16)
-    e = ServeLoop(model, params, eos_token=cfg.vocab_size - 1, **kw)
+    kw.setdefault("eos_token", cfg.vocab_size - 1)
+    e = ServeLoop(model, params, **kw)
     for r in trace:
         e.submit(Request(**r))
     done = e.run_until_drained(max_ticks=40_000)
@@ -198,7 +204,7 @@ class TestBoundedBudget:
         neighbour prefills — the exact stall the sync tick exhibits."""
         cfg, model, params = mt
         e = ServeLoop(model, params, batch_slots=2, max_len=256,
-                      prefill_chunk=16, eos_token=cfg.vocab_size - 1)
+                      prefill_chunk=16, eos_token=NO_EOS)
         e.submit(Request(uid=0, prompt=[1, 2, 3, 4], max_new_tokens=32))
         e.tick()  # uid 0 admits + finishes its single-chunk prefill
         assert e.slots[0].state == "decode"
@@ -252,7 +258,7 @@ class TestMidPrefillLifecycle:
     def _start_long_job(self, mt, **kw):
         cfg, model, params = mt
         e = ServeLoop(model, params, batch_slots=2, max_len=256,
-                      prefill_chunk=16, eos_token=cfg.vocab_size - 1,
+                      prefill_chunk=16, eos_token=NO_EOS,
                       audit=True, **kw)
         e.submit(Request(uid=0, prompt=[1, 2, 3, 4], max_new_tokens=6,
                          temperature=0.7))
@@ -269,6 +275,7 @@ class TestMidPrefillLifecycle:
             mt, [dict(uid=0, prompt=[1, 2, 3, 4], max_new_tokens=6,
                       temperature=0.7)],
             scheduler="hybrid", batch_slots=2, max_len=256,
+            eos_token=NO_EOS,
         )
         e, prompt = self._start_long_job(mt)
         assert e.cancel(1)
@@ -309,6 +316,7 @@ class TestMidPrefillLifecycle:
         _, ref = _drain(
             mt, [dict(uid=1, prompt=list(prompt), max_new_tokens=4)],
             scheduler="hybrid", batch_slots=2, max_len=256,
+            eos_token=NO_EOS,
         )
         assert next(
             tuple(r.tokens_out) for r in done if r.uid == 1

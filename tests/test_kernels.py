@@ -21,6 +21,16 @@ def _mk(shape, seed, dtype=jnp.float32):
     )
 
 
+def _identity_pages(x, block):
+    """An unpaged ``[bh, n, d]`` cache as the kernels address it:
+    ``[bh·n/block, block, d]`` pages plus the identity ``[bh, n/block]``
+    block table (row r's block j is page r·n/block + j)."""
+    bh, n, d = x.shape
+    nb = n // block
+    table = jnp.arange(bh * nb, dtype=jnp.int32).reshape(bh, nb)
+    return x.reshape(bh * nb, block, d), table
+
+
 SHAPES = [
     # (bh, n_q, n_k, d, block_q, block_k)
     (1, 128, 128, 32, 64, 64),
@@ -152,8 +162,9 @@ class TestFusedDecodeKernel:
         qp = q16.bit_plane(4).reshape(bh, G, d)
         qs = q16.scale.reshape(bh, G, 1)
         cl_bh = jnp.repeat(cl, H)
-        s0, s1 = dk.mpmrf_decode_filter_scores(
-            qp, qs, codes.reshape(bh, n, d), scales.reshape(bh, n // bk),
+        pages, table = _identity_pages(codes.reshape(bh, n, d), bk)
+        s0, s1 = dk.mpmrf_paged_filter_scores(
+            qp, qs, pages, scales.reshape(bh * (n // bk), 1), table,
             cl_bh, round_bits=(2, 4), key_block=bk, interpret=True,
         )
         r0, r1 = ref.mpmrf_decode_filter_ref(
@@ -242,11 +253,13 @@ class TestFusedDecodeKernel:
                 m = int(min(budget, n_live[b]))
                 idx[b, h, :m] = rng.choice(n_live[b], size=m, replace=False)
                 val[b, h, :m] = 1
-        out_k = dk.decode_gather_attention(
-            q.reshape(bh, G, d), k.reshape(bh, n, d), v.reshape(bh, n, d),
+        k_pages, table = _identity_pages(k.reshape(bh, n, d), bk)
+        v_pages, _ = _identity_pages(v.reshape(bh, n, d), bk)
+        out_k = dk.paged_decode_gather_attention(
+            q.reshape(bh, G, d), k_pages, v_pages,
             jnp.asarray(idx).reshape(bh, budget),
             jnp.asarray(val).reshape(bh, budget),
-            jnp.repeat(cl, H), key_block=bk, interpret=True,
+            table, jnp.repeat(cl, H), key_block=bk, interpret=True,
         ).reshape(B, H, G, d)
         out_ref = spa.decode_block_gather_attention(
             q, k, v,
@@ -364,8 +377,9 @@ class TestFusedDecodePaged:
         qp = q16.bit_plane(4).reshape(bh, G, d)
         qs = q16.scale.reshape(bh, G, 1)
         cl_bh = jnp.repeat(cl, H)
-        r0, r1 = dk.mpmrf_decode_filter_scores(
-            qp, qs, codes.reshape(bh, n, d), scales.reshape(bh, mb),
+        pages, table = _identity_pages(codes.reshape(bh, n, d), bk)
+        r0, r1 = dk.mpmrf_paged_filter_scores(
+            qp, qs, pages, scales.reshape(bh * mb, 1), table,
             cl_bh, round_bits=(2, 4), key_block=bk, interpret=True,
         )
         head_off = (jnp.arange(H, dtype=jnp.int32) * num_pages)
@@ -628,9 +642,10 @@ class TestFusedPrefillKernel:
         qs = q16.scale.reshape(bh, n_q, 1)
         qpos_bh = jnp.repeat(qpos, H, axis=0)
         ks_row = jnp.repeat(scales, bk, axis=-1).reshape(bh, n_k)
-        s0, s1 = pk.mpmrf_prefill_filter_scores(
-            qp, qs, qpos_bh, codes.reshape(bh, n_k, d), ks_row,
-            round_bits=(2, 4), query_block=bq, key_block=bk,
+        pages, table = _identity_pages(codes.reshape(bh, n_k, d), bk)
+        s0, s1 = pk.mpmrf_paged_prefill_filter_scores(
+            qp, qs, qpos_bh, pages, scales.reshape(bh * (n_k // bk), 1),
+            table, round_bits=(2, 4), query_block=bq, key_block=bk,
             interpret=True,
         )
         r0, r1 = ref.mpmrf_prefill_filter_ref(
@@ -677,12 +692,12 @@ class TestFusedPrefillKernel:
         )
 
         q16 = qlib.quantize_int16(q, axis=-1)
-        s0, s1 = pk.mpmrf_prefill_filter_scores(
+        pages, table = _identity_pages(codes.reshape(bh, n_k, d), bk)
+        s0, s1 = pk.mpmrf_paged_prefill_filter_scores(
             q16.bit_plane(4).reshape(bh, n_q, d),
             q16.scale.reshape(bh, n_q, 1),
             jnp.repeat(qpos, H, axis=0),
-            codes.reshape(bh, n_k, d),
-            jnp.repeat(scales, bk, axis=-1).reshape(bh, n_k),
+            pages, scales.reshape(bh * n_kb, 1), table,
             round_bits=(2, 4), query_block=bq, key_block=bk,
             interpret=True,
         )
@@ -719,6 +734,38 @@ class TestFusedPrefillKernel:
             q, k, v, EnergonConfig(impl="pallas", **kw),
             q_positions=qpos, layer_index=5, filter_cache=fc,
         )
+        np.testing.assert_allclose(
+            np.asarray(out_p), np.asarray(out_x), atol=1e-5
+        )
+
+    def test_key_tile_of_two_scale_blocks_matches_xla(self, monkeypatch):
+        """Unpaged cache whose key tiles span two resident scale blocks:
+        the fused path pools block pairs and must select exactly as the
+        XLA path does on the same planes."""
+        from repro.core import EnergonConfig, energon_attention
+
+        q, k, v, qpos, codes, scales, bq, bk = self._setup(seed=15)
+        fc = {"codes": codes, "scale": scales}
+        calls = []
+        fused = ops.fused_prefill_attention
+        monkeypatch.setattr(
+            ops, "fused_prefill_attention",
+            lambda *a, **kw: calls.append(kw["key_block"]) or fused(*a, **kw),
+        )
+        kw = dict(pruning_ratio=2.0, query_block=bq, key_block=2 * bk,
+                  decode_key_block=bk, min_prune_layer=0)
+        out_x, st_x = energon_attention(
+            q, k, v, EnergonConfig(impl="mpmrf_block", **kw),
+            q_positions=qpos, layer_index=5, filter_cache=fc,
+            telemetry=True,
+        )
+        out_p, st_p = energon_attention(
+            q, k, v, EnergonConfig(impl="pallas", **kw),
+            q_positions=qpos, layer_index=5, filter_cache=fc,
+            telemetry=True,
+        )
+        assert calls == [2 * bk]             # only the pallas impl fused
+        np.testing.assert_array_equal(np.asarray(st_p), np.asarray(st_x))
         np.testing.assert_allclose(
             np.asarray(out_p), np.asarray(out_x), atol=1e-5
         )
@@ -877,9 +924,9 @@ class TestFusedPrefillPaged:
         qp = q16.bit_plane(4).reshape(bh, n_q, d)
         qs = q16.scale.reshape(bh, n_q, 1)
         qpos_bh = jnp.repeat(qpos, H, axis=0)
-        r0, r1 = pk.mpmrf_prefill_filter_scores(
-            qp, qs, qpos_bh, codes.reshape(bh, n, d),
-            jnp.repeat(scales, bk, axis=-1).reshape(bh, n),
+        pages, table = _identity_pages(codes.reshape(bh, n, d), bk)
+        r0, r1 = pk.mpmrf_paged_prefill_filter_scores(
+            qp, qs, qpos_bh, pages, scales.reshape(bh * mb, 1), table,
             round_bits=(2, 4), query_block=bq, key_block=bk,
             interpret=True,
         )
@@ -920,6 +967,49 @@ class TestFusedPrefillPaged:
             outs[impl] = energon_paged_prefill_attention(
                 q, cache, jnp.asarray(tables), qpos, cfg, layer_index=5
             )
+        np.testing.assert_allclose(
+            np.asarray(outs["pallas"]), np.asarray(outs["mpmrf_block"]),
+            atol=1e-5,
+        )
+
+    @pytest.mark.parametrize("ratio", [2.0, 4.0])
+    def test_key_tile_of_two_pages_matches_xla_fallback(
+        self, ratio, monkeypatch
+    ):
+        """Key tiles twice the page size (phi3-mini's 128-row tiles over
+        64-row pages): the fused path engages, max-pools page pairs and
+        gathers page pairs, and its selection counts equal the XLA
+        fallback's exactly."""
+        from repro.core import (
+            EnergonConfig,
+            energon_paged_prefill_attention,
+        )
+
+        q, k, v, qpos, codes, scales, tables, pool, bq, bk = self._setup(
+            seed=12
+        )
+        cache = dict(k=pool["k"], v=pool["v"], k_codes=pool["codes"],
+                     k_scale=pool["scale"])
+        calls = []
+        fused = ops.fused_paged_prefill_attention
+        monkeypatch.setattr(
+            ops, "fused_paged_prefill_attention",
+            lambda *a, **kw: calls.append(kw["key_block"]) or fused(*a, **kw),
+        )
+        outs, stats = {}, {}
+        for impl in ("pallas", "mpmrf_block"):
+            cfg = EnergonConfig(impl=impl, pruning_ratio=ratio,
+                                query_block=bq, key_block=2 * bk,
+                                decode_key_block=bk, min_prune_layer=0,
+                                filter_cache_min_len=0)
+            outs[impl], stats[impl] = energon_paged_prefill_attention(
+                q, cache, jnp.asarray(tables), qpos, cfg, layer_index=5,
+                telemetry=True,
+            )
+        assert calls == [2 * bk]             # only the pallas impl fused
+        np.testing.assert_array_equal(
+            np.asarray(stats["pallas"]), np.asarray(stats["mpmrf_block"])
+        )
         np.testing.assert_allclose(
             np.asarray(outs["pallas"]), np.asarray(outs["mpmrf_block"]),
             atol=1e-5,

@@ -471,8 +471,8 @@ class TestServeEngine:
             energon or EnergonConfig(impl="mpmrf_block", pruning_ratio=2.0,
                                      decode_key_block=16, min_prune_layer=1)
         )
-        return cfg, ServeLoop(model, params, eos_token=cfg.vocab_size - 1,
-                              **kw)
+        kw.setdefault("eos_token", cfg.vocab_size - 1)
+        return cfg, ServeLoop(model, params, **kw)
 
     def test_dispatch_count_for_long_prompt(self):
         """Admitting a 256-token prompt with chunk 64 costs ≤ 5 jitted
@@ -672,8 +672,10 @@ class TestServeEngine:
             assert float(jnp.abs(leaf[:, 1]).max()) == 0
 
     def test_engine_metrics_split(self):
+        # an EOS id no model can emit: the request decodes all its
+        # tokens whatever the random weights sample first
         cfg, engine = self._engine(
-            batch_slots=2, max_len=64, prefill_chunk=4
+            batch_slots=2, max_len=64, prefill_chunk=4, eos_token=-1
         )
         engine.submit(Request(uid=0, prompt=[1, 2, 3, 4, 5, 6],
                               max_new_tokens=3))

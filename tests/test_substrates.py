@@ -21,6 +21,7 @@ from repro.runtime.fault_tolerance import (
     RetryPolicy,
     StepFailure,
     StragglerMonitor,
+    TransientStepError,
     retry_step,
 )
 
@@ -230,7 +231,7 @@ class TestFaultTolerance:
         def flaky():
             calls["n"] += 1
             if calls["n"] < 3:
-                raise RuntimeError("transient")
+                raise TransientStepError("transient")
             return "ok"
 
         assert retry_step(flaky, base_delay=0.0) == "ok"
@@ -238,10 +239,26 @@ class TestFaultTolerance:
 
     def test_retry_exhausts(self):
         def always_fails():
-            raise RuntimeError("down")
+            raise TransientStepError("down")
 
         with pytest.raises(StepFailure):
             retry_step(always_fails, max_retries=2, base_delay=0.0)
+
+    def test_device_runtime_error_is_not_retried(self):
+        """JAX's runtime errors subclass RuntimeError; they surface after
+        donation, so the default policy lets them propagate on the first
+        attempt instead of retrying against freed buffers."""
+        calls = {"n": 0}
+
+        def device_error():
+            calls["n"] += 1
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            retry_step(device_error, base_delay=0.0)
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            retry_step(device_error, policy=RetryPolicy(base_delay=0.0))
+        assert calls["n"] == 2
 
     def test_straggler_detection(self):
         mon = StragglerMonitor(window=20, threshold=3.0)
@@ -263,7 +280,7 @@ class TestFaultTolerance:
 
         def always_fails():
             calls["n"] += 1
-            raise RuntimeError("down")
+            raise TransientStepError("down")
 
         with pytest.raises(StepFailure):
             retry_step(
@@ -288,7 +305,7 @@ class TestFaultTolerance:
 
         def flaky():
             if len(seen) < 2:
-                raise RuntimeError("transient")
+                raise TransientStepError("transient")
             return "ok"
 
         out = retry_step(
