@@ -469,35 +469,38 @@ def _decode_pipeline(
 ):
     """Filter kernel → shared selection → gather kernel over page-major
     operands ``[n_pages, bk, d]`` addressed by the per-(batch, head)
-    table ``bt_bh [B·H, mb]``. Returns ``(out, stats_or_None)``."""
+    table ``bt_bh [B·H, mb]``, under the named scopes ``select`` and
+    ``gather``. Returns ``(out, stats_or_None)``."""
     batch, heads, g, d = q.shape
     bh = batch * heads
 
-    q16 = qlib.quantize_int16(q, axis=-1)
-    qp = q16.bit_plane(round_bits[-1]).reshape(bh, g, d)
-    qs = q16.scale.reshape(bh, g, 1)
-    cl_bh = jnp.repeat(cache_length.astype(jnp.int32), heads)
+    with jax.named_scope("select"):
+        q16 = qlib.quantize_int16(q, axis=-1)
+        qp = q16.bit_plane(round_bits[-1]).reshape(bh, g, d)
+        qs = q16.scale.reshape(bh, g, 1)
+        cl_bh = jnp.repeat(cache_length.astype(jnp.int32), heads)
 
-    s0, s1 = dec_kernel.mpmrf_paged_filter_scores(
-        qp, qs, codes_pages, scale_pages, bt_bh, cl_bh,
-        round_bits=tuple(round_bits),
-        key_block=key_block,
-        interpret=interpret,
-    )
+        s0, s1 = dec_kernel.mpmrf_paged_filter_scores(
+            qp, qs, codes_pages, scale_pages, bt_bh, cl_bh,
+            round_bits=tuple(round_bits),
+            key_block=key_block,
+            interpret=interpret,
+        )
 
-    idx, val, stats = _fused_decode_select(
-        s0, s1, cl_bh,
-        alphas=alphas, key_block=key_block, block_budget=block_budget,
-        keep_all=keep_all, keep_first=keep_first,
-        keep_diagonal=keep_diagonal,
-        live_budget=live_budget, heads=heads, with_stats=with_stats,
-    )
+        idx, val, stats = _fused_decode_select(
+            s0, s1, cl_bh,
+            alphas=alphas, key_block=key_block, block_budget=block_budget,
+            keep_all=keep_all, keep_first=keep_first,
+            keep_diagonal=keep_diagonal,
+            live_budget=live_budget, heads=heads, with_stats=with_stats,
+        )
 
-    out = dec_kernel.paged_decode_gather_attention(
-        q.reshape(bh, g, d), k_pages, v_pages,
-        idx, val, bt_bh, cl_bh,
-        key_block=key_block, scale=scale, interpret=interpret,
-    )
+    with jax.named_scope("gather"):
+        out = dec_kernel.paged_decode_gather_attention(
+            q.reshape(bh, g, d), k_pages, v_pages,
+            idx, val, bt_bh, cl_bh,
+            key_block=key_block, scale=scale, interpret=interpret,
+        )
     out = out.reshape(batch, heads, g, d)
     if with_stats:
         return out, stats.reshape(batch, heads, 4).sum(axis=1)
@@ -773,8 +776,9 @@ def _prefill_pipeline(
     Selection runs at ``key_block`` granularity, a whole number ``r``
     of pages: the kernels work per page, the filter's page maxima are
     max-pooled r at a time (max of maxima — exact), and each selected
-    key block expands to its r pages for the gather. Returns
-    ``(out, stats_or_None)``."""
+    key block expands to its r pages for the gather. The halves run
+    under the named scopes ``select`` and ``gather``, as in
+    :func:`_decode_pipeline`. Returns ``(out, stats_or_None)``."""
     batch, heads, n_q, d = q.shape
     page = k_pages.shape[-2]
     if key_block % page:
@@ -787,42 +791,44 @@ def _prefill_pipeline(
         raise ValueError(f"{mb} pages do not tile {key_block}-row key blocks")
     bh = batch * heads
 
-    q16 = qlib.quantize_int16(q, axis=-1)
-    qp = q16.bit_plane(round_bits[-1]).reshape(bh, n_q, d)
-    qs = q16.scale.reshape(bh, n_q, 1)
-    qpos_bh = jnp.repeat(q_positions.astype(jnp.int32), heads, axis=0)
+    with jax.named_scope("select"):
+        q16 = qlib.quantize_int16(q, axis=-1)
+        qp = q16.bit_plane(round_bits[-1]).reshape(bh, n_q, d)
+        qs = q16.scale.reshape(bh, n_q, 1)
+        qpos_bh = jnp.repeat(q_positions.astype(jnp.int32), heads, axis=0)
 
-    s0, s1 = pre_kernel.mpmrf_paged_prefill_filter_scores(
-        qp, qs, qpos_bh, codes_pages, scale_pages, bt_bh,
-        round_bits=tuple(round_bits),
-        query_block=query_block,
-        key_block=page,
-        interpret=interpret,
-    )
-    if r > 1:
-        n_qb = n_q // query_block
-        s0 = jnp.max(s0.reshape(bh, n_qb, mb // r, r), axis=-1)
-        s1 = jnp.max(s1.reshape(bh, n_qb, mb // r, r), axis=-1)
+        s0, s1 = pre_kernel.mpmrf_paged_prefill_filter_scores(
+            qp, qs, qpos_bh, codes_pages, scale_pages, bt_bh,
+            round_bits=tuple(round_bits),
+            query_block=query_block,
+            key_block=page,
+            interpret=interpret,
+        )
+        if r > 1:
+            n_qb = n_q // query_block
+            s0 = jnp.max(s0.reshape(bh, n_qb, mb // r, r), axis=-1)
+            s1 = jnp.max(s1.reshape(bh, n_qb, mb // r, r), axis=-1)
 
-    idx, val, stats = _fused_prefill_select(
-        s0, s1,
-        round_bits=round_bits, alphas=alphas,
-        query_block=query_block, key_block=key_block,
-        block_budget=block_budget, keep_all=keep_all,
-        keep_first=keep_first, keep_diagonal=keep_diagonal,
-        diag_blocks=diag_blocks, heads=heads, with_stats=with_stats,
-    )
-    if r > 1:
-        idx = (idx[..., None] * r + jnp.arange(r, dtype=idx.dtype))
-        idx = idx.reshape(*idx.shape[:-2], -1)
-        val = jnp.repeat(val, r, axis=-1)
+        idx, val, stats = _fused_prefill_select(
+            s0, s1,
+            round_bits=round_bits, alphas=alphas,
+            query_block=query_block, key_block=key_block,
+            block_budget=block_budget, keep_all=keep_all,
+            keep_first=keep_first, keep_diagonal=keep_diagonal,
+            diag_blocks=diag_blocks, heads=heads, with_stats=with_stats,
+        )
+        if r > 1:
+            idx = (idx[..., None] * r + jnp.arange(r, dtype=idx.dtype))
+            idx = idx.reshape(*idx.shape[:-2], -1)
+            val = jnp.repeat(val, r, axis=-1)
 
-    out = pre_kernel.paged_prefill_gather_attention(
-        q.reshape(bh, n_q, d), qpos_bh, k_pages, v_pages,
-        idx, val, bt_bh,
-        query_block=query_block, key_block=page,
-        scale=scale, interpret=interpret,
-    )
+    with jax.named_scope("gather"):
+        out = pre_kernel.paged_prefill_gather_attention(
+            q.reshape(bh, n_q, d), qpos_bh, k_pages, v_pages,
+            idx, val, bt_bh,
+            query_block=query_block, key_block=page,
+            scale=scale, interpret=interpret,
+        )
     out = out.reshape(batch, heads, n_q, d)
     if with_stats:
         return out, stats.reshape(batch, heads, 4).sum(axis=1)

@@ -476,8 +476,6 @@ def _project_update_fold_paged(
     whole pool (every page's codes/scale equal a fresh per-page
     quantization of its float rows at every step).
     """
-    from repro.runtime import paged_cache as pgc
-
     batch, chunk, _ = x.shape
     ps = filter_block if filter_block > 0 else 0
     if ps <= 0:
@@ -498,6 +496,34 @@ def _project_update_fold_paged(
         allow_uneven=True,
     )
 
+    with jax.named_scope("page_write"):
+        new_cache = _write_pages(
+            cache, k_new, v_new, positions, block_table, ps,
+            write_mask=write_mask,
+        )
+
+    groups = num_heads // num_kv_heads
+    head_dim = q.shape[-1]
+    if groups > 1:
+        q = q.reshape(batch, num_kv_heads, groups * chunk, head_dim)
+    return q, new_cache
+
+
+def _write_pages(
+    cache: Dict[str, jax.Array],
+    k_new: jax.Array,
+    v_new: jax.Array,
+    positions: jax.Array,
+    block_table: jax.Array,
+    ps: int,
+    write_mask: Optional[jax.Array] = None,
+) -> Dict[str, jax.Array]:
+    """The paged write site: scatter the new K/V rows ``[B, KV, C, hd]``
+    through the block table and refresh the int16 filter planes of the
+    pages they touch (see :func:`_project_update_fold_paged`)."""
+    from repro.runtime import paged_cache as pgc
+
+    num_kv_heads, chunk = k_new.shape[1], k_new.shape[2]
     rowid = pgc.paged_row_targets(
         positions, block_table, ps, write_mask=write_mask
     )                                        # [B, C]
@@ -558,12 +584,7 @@ def _project_update_fold_paged(
             codes = codes.astype(jnp.int16)
         new_cache["k_codes"] = codes
         new_cache["k_scale"] = scales
-
-    groups = num_heads // num_kv_heads
-    head_dim = q.shape[-1]
-    if groups > 1:
-        q = q.reshape(batch, num_kv_heads, groups * chunk, head_dim)
-    return q, new_cache
+    return new_cache
 
 
 def paged_prefill_attention_block(

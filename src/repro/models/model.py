@@ -183,12 +183,13 @@ class LMModel:
         return shd.constrain(x, ("dp", None, None))
 
     def _logits_out(self, params, x) -> jax.Array:
-        x = L.apply_norm(self.cfg.norm, params["final_norm"], x)
-        if self.cfg.tie_embeddings:
-            logits = L.tied_lm_logits(params["embed"], x)
-        else:
-            logits = L.lm_logits(params["lm_head"], x)
-        return shd.constrain(logits, ("dp", None, "model"))
+        with jax.named_scope("head"):
+            x = L.apply_norm(self.cfg.norm, params["final_norm"], x)
+            if self.cfg.tie_embeddings:
+                logits = L.tied_lm_logits(params["embed"], x)
+            else:
+                logits = L.lm_logits(params["lm_head"], x)
+            return shd.constrain(logits, ("dp", None, "model"))
 
     def _tfm_block_fn(self):
         cfg = self.cfg
@@ -616,23 +617,25 @@ class LMModel:
         """Shared decode/prefill block body: pre-norm attention +
         residual, then the MoE/MLP half. ``attn_call(params, x_normed,
         kv_cache) -> (h, new_cache)`` — ``(h, new_cache, stats)`` with
-        ``telemetry``, threaded through unchanged."""
+        ``telemetry``, threaded through unchanged. The two halves run
+        under the named scopes ``attention`` and ``mlp`` (metadata for
+        the device trace only)."""
         cfg = self.cfg
-        res = attn_call(
-            layer_params["attn"],
-            L.apply_norm(cfg.norm, layer_params["norm_attn"], x),
-            kv_cache,
-        )
+        xn = L.apply_norm(cfg.norm, layer_params["norm_attn"], x)
+        with jax.named_scope("attention"):
+            res = attn_call(layer_params["attn"], xn, kv_cache)
         if telemetry:
             h, new_cache, stats = res
         else:
             h, new_cache = res
         x = x + h
         h_in = L.apply_norm(cfg.norm, layer_params["norm_mlp"], x)
-        if self._moe_cfg() is not None:
-            h, _ = moe_lib.apply_moe(layer_params["moe"], h_in, self._moe_cfg())
-        else:
-            h = L.apply_mlp(layer_params["mlp"], h_in, cfg.activation)
+        with jax.named_scope("mlp"):
+            if self._moe_cfg() is not None:
+                h, _ = moe_lib.apply_moe(
+                    layer_params["moe"], h_in, self._moe_cfg())
+            else:
+                h = L.apply_mlp(layer_params["mlp"], h_in, cfg.activation)
         if telemetry:
             return x + h, new_cache, stats
         return x + h, new_cache

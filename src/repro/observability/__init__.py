@@ -43,6 +43,7 @@ from repro.observability.trace import (  # noqa: F401
     EventTrace,
     TraceEvent,
     export_chrome_trace,
+    span,
     validate_chrome_trace,
 )
 
@@ -151,5 +152,5 @@ __all__ = [
     "EventTrace", "TraceEvent", "export_chrome_trace",
     "validate_chrome_trace", "SPAN_EVENTS", "COUNTER_EVENTS",
     "RELEASE_EVENTS", "STAT_FIELDS", "SparsityAggregator",
-    "Observability",
+    "Observability", "span",
 ]

@@ -21,7 +21,6 @@ of that bucket (tested against a numpy oracle).
 from __future__ import annotations
 
 import bisect
-import json
 import re
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -242,10 +241,6 @@ class MetricsRegistry:
                 lines.append(f"{pname}_sum {_fmt(m.sum)}")
                 lines.append(f"{pname}_count {m.count}")
         return "\n".join(lines) + "\n"
-
-    def write_json(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.snapshot(), f, indent=2, sort_keys=True)
 
     def merge(self, other: "MetricsRegistry",
               rename=None) -> "MetricsRegistry":

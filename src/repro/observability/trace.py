@@ -19,6 +19,13 @@ prefill-wave duration spans, an allocator lane (page evictions), a
 chaos lane (injected faults), and counter tracks for pool occupancy /
 queue depth / live slots. `validate_chrome_trace` is the schema check
 CI and the tests share.
+
+`span` is the engine's one host clock for its phases: it opens a
+``jax.profiler.TraceAnnotation`` (a TraceMe, so the span lands on the
+profiler's host plane on the device trace's clock when a profiler
+session runs, and costs a flag check when none does), times the block,
+and emits the duration-carrying `SPAN_EVENTS` into an `EventTrace`
+when the engine has one.
 """
 
 from __future__ import annotations
@@ -28,6 +35,8 @@ import json
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
+
+import jax
 
 #: Event names with a duration, rendered as complete ("X") spans on the
 #: scheduler lane.
@@ -116,6 +125,50 @@ class EventTrace:
 
     def __len__(self) -> int:
         return len(self._events)
+
+
+class span:
+    """Context manager for one named host span of the engine:
+    ``with span("serve.decode", trace) as s: ...``.
+
+    Opens ``jax.profiler.TraceAnnotation(name, **args)`` and yields
+    itself. ``s.seconds`` is the time since the span opened (fixed once
+    it closes); ``s.emit(event, **kw)`` emits ``event`` into ``trace``
+    (when there is one) with that duration as ``dur`` and returns it,
+    so an event and the counter it feeds share one reading;
+    ``s.annotate(**args)`` adds args known only inside the span.
+    """
+
+    __slots__ = ("_me", "_trace", "_t0", "_t1")
+
+    def __init__(self, name: str, trace: Optional["EventTrace"] = None,
+                 **args):
+        self._me = jax.profiler.TraceAnnotation(name, **args)
+        self._trace = trace
+        self._t0 = self._t1 = None
+
+    def __enter__(self) -> "span":
+        self._me.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._t1 = time.perf_counter()
+        self._me.__exit__(*exc)
+
+    @property
+    def seconds(self) -> float:
+        end = self._t1 if self._t1 is not None else time.perf_counter()
+        return end - self._t0
+
+    def emit(self, event: str, **kw) -> float:
+        dur = self.seconds
+        if self._trace is not None:
+            self._trace.emit(event, dur=dur, **kw)
+        return dur
+
+    def annotate(self, **args) -> None:
+        self._me.set_metadata(**args)
 
 
 def _us(seconds: float) -> float:

@@ -97,7 +97,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed import sharding as shd
 from repro.models import LMModel
-from repro.observability import Observability
+from repro.observability import Observability, span
 from repro.observability.metrics import DEFAULT_LATENCY_BOUNDS, MetricsRegistry
 from repro.runtime.fault_tolerance import (
     FaultInjector,
@@ -632,6 +632,8 @@ class ServeLoop:
         # functions, so the disabled path's HLO is byte-identical to an
         # engine built before this layer existed.
         self.obs = observability
+        #: where `span` emits the duration-carrying events (None: none)
+        self._trace = observability.trace if observability else None
         self._telemetry = (
             observability is not None and observability.device_telemetry
         )
@@ -1137,6 +1139,7 @@ class ServeLoop:
         runs here; under the hybrid scheduler this only *allocates*
         (pages, slot, RNG key) and enqueues a :class:`_PrefillJob` —
         `_prefill_tick` then advances every job one chunk per tick.
+        Returns the uids admitted.
         """
         chunked, sequential = [], []
         admitted_slots: List[int] = []
@@ -1167,6 +1170,7 @@ class ServeLoop:
                 req = next(cand_iter, None)
             if not admitted:
                 break
+        uids = [self.slots[i].uid for i in admitted_slots]
         if self.paged:
             # paged slot hygiene happens per *page*, at allocation:
             # fresh pages are zeroed, attached pages carry live shared
@@ -1199,6 +1203,7 @@ class ServeLoop:
                 self._enqueue_prefill_jobs(chunked)
             else:
                 self._prefill_slots(chunked)
+        return uids
 
     def _try_admit(self, i: int, req: Request, now: float,
                    new_pages: List[int], admitted_slots: List[int],
@@ -1334,70 +1339,71 @@ class ServeLoop:
         contributes nothing and restores purely by table aliasing.
         """
         C = self.prefill_chunk
-        t0 = time.perf_counter()
-        n_chunks = max(
-            -(-(len(seq) - skip) // C) for _, _, seq, _, skip in admitted
-        )
-        bt = self._device_block_table() if self.paged else None
-        last_logits = {}
-        logits = None
-        use_t = self._telemetry and self.prefill_fn_t is not None
-        stats_chunks = []
-        for c in range(n_chunks):
-            toks = np.zeros((self.batch_slots, C), np.int32)
-            # position sentinel max_len ⇒ no cache write, output ignored
-            # (idle slots, already-finished prompts and ragged tails all
-            # share one compiled shape).
-            pos = np.full((self.batch_slots, C), self.max_len, np.int32)
-            for i, req, seq, _, skip in admitted:
-                lo = skip + c * C
-                part = seq[lo:lo + C]
-                if part:
-                    toks[i, :len(part)] = part
-                    pos[i, :len(part)] = lo + np.arange(len(part))
-            inputs = {
-                "tokens": jnp.asarray(toks), "positions": jnp.asarray(pos),
-            }
-            if bt is not None:
-                inputs["block_table"] = bt
-            if use_t:
-                logits, self.cache, st = self._dispatch(
-                    self.prefill_fn_t,
-                    self.params, self.cache, inputs, self.cache_index,
-                )
-                stats_chunks.append(st)
-            else:
-                logits, self.cache = self._dispatch(
-                    self.prefill_fn,
-                    self.params, self.cache, inputs, self.cache_index,
-                )
-            self.metrics.prefill_dispatches += 1
-            self._emit("prefill_chunk", site="prefill",
-                       chunk=c, slots=len(admitted))
-            for i, req, seq, resumed, skip in admitted:
-                lo = skip + c * C
-                if not resumed and lo < len(seq) <= lo + C:
-                    last_logits[i] = logits[i, len(seq) - 1 - lo]
-        # jax dispatch is async: sync before stopping the clock so the
-        # prefill/decode throughput split reflects device time, not
-        # dispatch time.
-        if last_logits or logits is not None:
-            jax.block_until_ready(
-                list(last_logits.values()) if last_logits else logits
+        with span("serve.prefill", self._trace,
+                  uids=[req.uid for _, req, *_ in admitted]) as sp:
+            n_chunks = max(
+                -(-(len(seq) - skip) // C) for _, _, seq, _, skip in admitted
             )
-        for i, req, seq, _, skip in admitted:
-            self.cache_index = self.cache_index.at[i].set(len(seq))
-            self._lengths[i] = len(seq)
-            self.metrics.prefill_tokens += len(seq) - skip
-        self.metrics.prefill_time += time.perf_counter() - t0
-        self._emit("prefill_wave", site="prefill",
-                   dur=time.perf_counter() - t0,
-                   chunks=n_chunks, slots=len(admitted))
-        if stats_chunks:
-            # one host sync for the whole wave; stats are tiny [L, B, 4]
-            for st in jax.device_get(stats_chunks):
-                self.obs.record_prefill_stats(np.asarray(st))
-        self._complete_prefill(admitted, last_logits)
+            bt = self._device_block_table() if self.paged else None
+            last_logits = {}
+            logits = None
+            use_t = self._telemetry and self.prefill_fn_t is not None
+            stats_chunks = []
+            for c in range(n_chunks):
+                toks = np.zeros((self.batch_slots, C), np.int32)
+                # position sentinel max_len ⇒ no cache write, output ignored
+                # (idle slots, already-finished prompts and ragged tails all
+                # share one compiled shape).
+                pos = np.full((self.batch_slots, C), self.max_len, np.int32)
+                for i, req, seq, _, skip in admitted:
+                    lo = skip + c * C
+                    part = seq[lo:lo + C]
+                    if part:
+                        toks[i, :len(part)] = part
+                        pos[i, :len(part)] = lo + np.arange(len(part))
+                inputs = {
+                    "tokens": jnp.asarray(toks), "positions": jnp.asarray(pos),
+                }
+                if bt is not None:
+                    inputs["block_table"] = bt
+                if use_t:
+                    logits, self.cache, st = self._dispatch(
+                        self.prefill_fn_t,
+                        self.params, self.cache, inputs, self.cache_index,
+                    )
+                    stats_chunks.append(st)
+                else:
+                    logits, self.cache = self._dispatch(
+                        self.prefill_fn,
+                        self.params, self.cache, inputs, self.cache_index,
+                    )
+                self.metrics.prefill_dispatches += 1
+                self._emit("prefill_chunk", site="prefill",
+                           chunk=c, slots=len(admitted))
+                for i, req, seq, resumed, skip in admitted:
+                    lo = skip + c * C
+                    if not resumed and lo < len(seq) <= lo + C:
+                        last_logits[i] = logits[i, len(seq) - 1 - lo]
+            # jax dispatch is async: sync before reading the clock so the
+            # prefill/decode throughput split reflects device time, not
+            # dispatch time.
+            if last_logits or logits is not None:
+                with span("serve.prefill.sync"):
+                    jax.block_until_ready(
+                        list(last_logits.values()) if last_logits else logits
+                    )
+            for i, req, seq, _, skip in admitted:
+                self.cache_index = self.cache_index.at[i].set(len(seq))
+                self._lengths[i] = len(seq)
+                self.metrics.prefill_tokens += len(seq) - skip
+            self.metrics.prefill_time += sp.emit(
+                "prefill_wave", site="prefill", chunks=n_chunks,
+                slots=len(admitted))
+            if stats_chunks:
+                # one host sync for the whole wave; stats are tiny [L, B, 4]
+                for st in jax.device_get(stats_chunks):
+                    self.obs.record_prefill_stats(np.asarray(st))
+            self._complete_prefill(admitted, last_logits)
 
     def _complete_prefill(self, entries, last_logits):
         """Shared tail of a synchronous wave and of hybrid chunk
@@ -1476,73 +1482,74 @@ class ServeLoop:
         if not jobs:
             return
         C = self.prefill_chunk
-        t0 = time.perf_counter()
-        bt = self._device_block_table() if self.paged else None
-        toks = np.zeros((self.batch_slots, C), np.int32)
-        # position sentinel max_len ⇒ no cache write, output ignored
-        # (idle/decoding slots and ragged tails share one compiled
-        # shape).
-        pos = np.full((self.batch_slots, C), self.max_len, np.int32)
-        consumed: Dict[int, int] = {}
-        for i, job in jobs:
-            part = job.seq[job.pos:job.pos + C]
-            toks[i, :len(part)] = part
-            pos[i, :len(part)] = job.pos + np.arange(len(part))
-            consumed[i] = len(part)
-        inputs = {
-            "tokens": jnp.asarray(toks), "positions": jnp.asarray(pos),
-        }
-        if bt is not None:
-            inputs["block_table"] = bt
-        use_t = self._telemetry and self.prefill_fn_t is not None
-        stats = None
-        if use_t:
-            logits, self.cache, stats = self._dispatch(
-                self.prefill_fn_t,
-                self.params, self.cache, inputs, self.cache_index,
-            )
-        else:
-            logits, self.cache = self._dispatch(
-                self.prefill_fn,
-                self.params, self.cache, inputs, self.cache_index,
-            )
-        self.metrics.prefill_dispatches += 1
-        self._emit("prefill_chunk", site="prefill",
-                   chunk=min((j.pos - j.skip) // C for _, j in jobs),
-                   slots=len(jobs))
-        finished = []
-        last_logits = {}
-        for i, job in jobs:
-            lo, job.pos = job.pos, job.pos + consumed[i]
-            # per-chunk accounting (the sync wave counts per slot at
-            # wave end — same totals) keeps the stall detector's
-            # progress marker advancing on prefill-only ticks
-            self.metrics.prefill_tokens += consumed[i]
-            if job.pos >= len(job.seq):
-                finished.append(
-                    (i, job.req, job.seq, job.resumed, job.skip)
+        with span("serve.prefill", self._trace,
+                  uids=[job.req.uid for _, job in jobs]) as sp:
+            bt = self._device_block_table() if self.paged else None
+            toks = np.zeros((self.batch_slots, C), np.int32)
+            # position sentinel max_len ⇒ no cache write, output ignored
+            # (idle/decoding slots and ragged tails share one compiled
+            # shape).
+            pos = np.full((self.batch_slots, C), self.max_len, np.int32)
+            consumed: Dict[int, int] = {}
+            for i, job in jobs:
+                part = job.seq[job.pos:job.pos + C]
+                toks[i, :len(part)] = part
+                pos[i, :len(part)] = job.pos + np.arange(len(part))
+                consumed[i] = len(part)
+            inputs = {
+                "tokens": jnp.asarray(toks), "positions": jnp.asarray(pos),
+            }
+            if bt is not None:
+                inputs["block_table"] = bt
+            use_t = self._telemetry and self.prefill_fn_t is not None
+            stats = None
+            if use_t:
+                logits, self.cache, stats = self._dispatch(
+                    self.prefill_fn_t,
+                    self.params, self.cache, inputs, self.cache_index,
                 )
-                if not job.resumed:
-                    last_logits[i] = logits[i, len(job.seq) - 1 - lo]
-        # sync before stopping the clock: prefill_time must reflect
-        # device time for the ITL tick-phase attribution to be truthful
-        jax.block_until_ready(
-            list(last_logits.values()) if last_logits else logits
-        )
-        self.metrics.prefill_time += time.perf_counter() - t0
-        self._emit("prefill_tick", site="prefill",
-                   dur=time.perf_counter() - t0, slots=len(jobs),
-                   finished=len(finished))
-        if use_t and stats is not None:
-            self.obs.record_prefill_stats(
-                np.asarray(jax.device_get(stats))
-            )
-        for i, *_ in finished:
-            job = self._prefill_jobs.pop(i)
-            self.cache_index = self.cache_index.at[i].set(len(job.seq))
-            self._lengths[i] = len(job.seq)
-        if finished:
-            self._complete_prefill(finished, last_logits)
+            else:
+                logits, self.cache = self._dispatch(
+                    self.prefill_fn,
+                    self.params, self.cache, inputs, self.cache_index,
+                )
+            self.metrics.prefill_dispatches += 1
+            self._emit("prefill_chunk", site="prefill",
+                       chunk=min((j.pos - j.skip) // C for _, j in jobs),
+                       slots=len(jobs))
+            finished = []
+            last_logits = {}
+            for i, job in jobs:
+                lo, job.pos = job.pos, job.pos + consumed[i]
+                # per-chunk accounting (the sync wave counts per slot at
+                # wave end — same totals) keeps the stall detector's
+                # progress marker advancing on prefill-only ticks
+                self.metrics.prefill_tokens += consumed[i]
+                if job.pos >= len(job.seq):
+                    finished.append(
+                        (i, job.req, job.seq, job.resumed, job.skip)
+                    )
+                    if not job.resumed:
+                        last_logits[i] = logits[i, len(job.seq) - 1 - lo]
+            # sync before reading the clock: prefill_time must reflect
+            # device time for the ITL tick-phase attribution to be truthful
+            with span("serve.prefill.sync"):
+                jax.block_until_ready(
+                    list(last_logits.values()) if last_logits else logits
+                )
+            self.metrics.prefill_time += sp.emit(
+                "prefill_tick", site="prefill", slots=len(jobs),
+                finished=len(finished))
+            if use_t and stats is not None:
+                self.obs.record_prefill_stats(
+                    np.asarray(jax.device_get(stats))
+                )
+            for i, *_ in finished:
+                job = self._prefill_jobs.pop(i)
+                self.cache_index = self.cache_index.at[i].set(len(job.seq))
+                self._lengths[i] = len(job.seq)
+            if finished:
+                self._complete_prefill(finished, last_logits)
 
     def _sequential_prefill_wave(self, admitted):
         """Token-by-token admission for models without a chunked-prefill
@@ -1552,38 +1559,40 @@ class ServeLoop:
         not sum(L_i)-k. The `active` mask gates recurrent-state updates
         to exactly the slots that consumed a token, so live decode
         neighbours are never advanced on garbage inputs."""
-        t0 = time.perf_counter()
-        n_steps = max(len(req.prompt) - 1 for _, req in admitted)
-        logits = None
-        for t in range(max(n_steps, 0)):
-            tokens = np.full((self.batch_slots, 1), self.eos, np.int32)
-            active = np.zeros((self.batch_slots,), bool)
+        with span("serve.prefill", self._trace,
+                  uids=[req.uid for _, req in admitted]) as sp:
+            n_steps = max(len(req.prompt) - 1 for _, req in admitted)
+            logits = None
+            for t in range(max(n_steps, 0)):
+                tokens = np.full((self.batch_slots, 1), self.eos, np.int32)
+                active = np.zeros((self.batch_slots,), bool)
+                for i, req in admitted:
+                    if t < len(req.prompt) - 1:
+                        tokens[i, 0] = req.prompt[t]
+                        active[i] = True
+                inputs = {
+                    "tokens": jnp.asarray(tokens),
+                    "active": jnp.asarray(active),
+                }
+                if self.paged:
+                    inputs["block_table"] = self._device_block_table()
+                logits, self.cache = self._dispatch(
+                    self.step_fn,
+                    self.params, self.cache, inputs, self.cache_index,
+                )
+                self.cache_index = self.cache_index + jnp.asarray(
+                    active, jnp.int32
+                )
+                self._lengths += active
+                self.metrics.prefill_dispatches += 1
+                self.metrics.prefill_tokens += int(active.sum())
+            if logits is not None:
+                with span("serve.prefill.sync"):
+                    jax.block_until_ready(logits)
+            self.metrics.prefill_time += sp.seconds
             for i, req in admitted:
-                if t < len(req.prompt) - 1:
-                    tokens[i, 0] = req.prompt[t]
-                    active[i] = True
-            inputs = {
-                "tokens": jnp.asarray(tokens),
-                "active": jnp.asarray(active),
-            }
-            if self.paged:
-                inputs["block_table"] = self._device_block_table()
-            logits, self.cache = self._dispatch(
-                self.step_fn,
-                self.params, self.cache, inputs, self.cache_index,
-            )
-            self.cache_index = self.cache_index + jnp.asarray(
-                active, jnp.int32
-            )
-            self._lengths += active
-            self.metrics.prefill_dispatches += 1
-            self.metrics.prefill_tokens += int(active.sum())
-        if logits is not None:
-            jax.block_until_ready(logits)
-        self.metrics.prefill_time += time.perf_counter() - t0
-        for i, req in admitted:
-            req._next_input = req.prompt[-1] if req.prompt else self.eos
-            req.state = "decode"
+                req._next_input = req.prompt[-1] if req.prompt else self.eos
+                req.state = "decode"
 
     def _release_slot(self, i: int):
         """Clear slot state; in paged mode its pages free *eagerly*."""
@@ -1728,132 +1737,164 @@ class ServeLoop:
         token for every decode-state slot (quarantining any slot whose
         logits go non-finite). Under ``scheduler="sync"`` admission
         runs its entire prefill wave inline instead and every live slot
-        is in decode state by the time the decode step dispatches."""
-        if self.obs is not None:
-            self.obs.trace.tick = self.metrics.ticks
-        self._expire_deadlines()
-        if self._injector is not None:
-            self._injected_preempt_storm()
-        self._admit()
-        if self._hybrid and self._prefill_jobs:
-            self._prefill_tick()
-        live = [
-            i for i, r in enumerate(self.slots)
-            if r is not None
-            and (not self._hybrid or r.state == "decode")
-        ]
-        if not live:
-            self._end_tick()
-            return
-        if self.paged:
-            live = self._ensure_decode_capacity(live)
-            self.metrics.peak_pages_in_use = \
-                self.allocator.peak_pages_in_use
-            if not live:
+        is in decode state by the time the decode step dispatches.
+
+        Each phase is a `span` nested under ``serve.tick`` (DESIGN.md
+        §8): ``serve.admit``, ``serve.prefill`` (with
+        ``serve.prefill.sync``), ``serve.decode`` (``.inputs``,
+        ``.dispatch``, ``.register``, ``.sync``), ``serve.commit`` and
+        ``serve.end``."""
+        with span("serve.tick"):
+            if self.obs is not None:
+                self.obs.trace.tick = self.metrics.ticks
+            with span("serve.admit") as sp:
+                self._expire_deadlines()
+                if self._injector is not None:
+                    self._injected_preempt_storm()
+                sp.annotate(uids=self._admit())
+            if self._hybrid and self._prefill_jobs:
+                self._prefill_tick()
+            live = [
+                i for i, r in enumerate(self.slots)
+                if r is not None
+                and (not self._hybrid or r.state == "decode")
+            ]
+            step = self._decode(live) if live else None
+            if step is not None:
+                with span("serve.commit"):
+                    self._commit_step(*step)
+            with span("serve.end"):
                 self._end_tick()
-                return
-        t0 = time.perf_counter()
-        tokens = np.full((self.batch_slots, 1), self.eos, np.int32)
-        active = np.zeros((self.batch_slots,), bool)
-        for i in live:
-            tokens[i, 0] = self.slots[i]._next_input
-            active[i] = True
-        inputs = {
-            "tokens": jnp.asarray(tokens), "active": jnp.asarray(active),
-        }
-        if self.paged:
-            inputs["block_table"] = self._device_block_table()
-        # Unpaged decode writes K/V *positionally* at cache_index with no
-        # active gating ("self-healing": an idle slot's garbage row is
-        # overwritten by the next prefill before it can be read). Under
-        # the hybrid scheduler an inactive slot can be *mid-prefill* —
-        # rows already written by earlier chunks must not be clobbered —
-        # so inactive slots get the max_len sentinel, whose one-hot
-        # write row is all zeros (no write). The paged path already
-        # drops idle writes via its write_mask.
-        step_index = self.cache_index
-        if not self.paged:
-            step_index = jnp.where(
-                jnp.asarray(active), self.cache_index, self.max_len
-            )
-        step_stats = None
-        if self._telemetry and self.step_fn_t is not None:
-            logits, self.cache, step_stats = self._dispatch(
-                self.step_fn_t,
-                self.params, self.cache, inputs, step_index,
-            )
-        else:
-            logits, self.cache = self._dispatch(
-                self.step_fn,
-                self.params, self.cache, inputs, step_index,
-            )
-        self.cache_index = self.cache_index + jnp.asarray(active, jnp.int32)
-        self._lengths += active
-        if self.paged and self.sharing:
-            # a decode append that just *filled* a page freezes it:
-            # register its content (prompt + written generations) so a
-            # preempted-and-resumed twin — or an identical re-request —
-            # can attach instead of re-prefilling. Registered pages are
-            # immutable; the slot's next append starts a new page. The
-            # registration re-walks the slot's chain from the root —
-            # O(len) host work per page fill, bounded by the engine's
-            # rows ≤ max_len invariant (≤ max_len²/bk per request, dict
-            # lookups on small tuples) — noise next to a decode
-            # dispatch.
-            bk = self.layout.page_size
-            for i in live:
-                n = int(self._lengths[i])
-                if n and n % bk == 0:
-                    req = self.slots[i]
-                    self.allocator.register_prefix(
-                        i, req.prompt + req.tokens_out
-                    )
-        if self._injector is not None:
-            doomed = self._injector.poison_decode(
-                [self.slots[i].uid for i in live]
-            )
-            if doomed:
-                pmask = np.zeros((self.batch_slots,), bool)
+
+    def _decode(self, live: List[int]):
+        """One decode step over the ``live`` slots: reserve their next
+        rows, dispatch the step and the sampler, register filled pages,
+        and wait for the sampled tokens. Returns ``(live, next_tokens,
+        finite)`` for the commit, or None when capacity reservation
+        preempted every slot."""
+        with span("serve.decode", self._trace) as sp:
+            with span("serve.decode.inputs"):
+                if self.paged:
+                    live = self._ensure_decode_capacity(live)
+                    self.metrics.peak_pages_in_use = \
+                        self.allocator.peak_pages_in_use
+                    if not live:
+                        return None
+                tokens = np.full((self.batch_slots, 1), self.eos, np.int32)
+                active = np.zeros((self.batch_slots,), bool)
                 for i in live:
-                    if self.slots[i].uid in doomed:
-                        pmask[i] = True
-                logits = _poison_logits(logits, jnp.asarray(pmask))
-        next_tokens, self.slot_keys, finite = _sample_step(
-            logits, jnp.asarray(self._temps), self.slot_keys,
-            inputs["active"],
-        )
-        if step_stats is not None:
-            # stats ride the device_get the engine already pays for the
-            # sampled tokens — no extra host sync on the telemetry path.
-            next_tokens, finite, stats_host = jax.device_get(
-                (next_tokens, finite, step_stats)
-            )
-            self.obs.record_decode_stats(np.asarray(stats_host), slots=live)
-        else:
-            next_tokens, finite = jax.device_get((next_tokens, finite))
-        if self._injector is not None:
-            # injected straggler: the sleep lands inside decode_time so
-            # the StragglerMonitor sees it like a real slow step.
-            delay = self._injector.step_delay()
-            if delay:
-                time.sleep(delay)
-        self.metrics.decode_dispatches += 1
-        elapsed = time.perf_counter() - t0
+                    tokens[i, 0] = self.slots[i]._next_input
+                    active[i] = True
+                inputs = {
+                    "tokens": jnp.asarray(tokens),
+                    "active": jnp.asarray(active),
+                }
+                if self.paged:
+                    inputs["block_table"] = self._device_block_table()
+                # Unpaged decode writes K/V *positionally* at cache_index
+                # with no active gating ("self-healing": an idle slot's
+                # garbage row is overwritten by the next prefill before
+                # it can be read). Under the hybrid scheduler an inactive
+                # slot can be *mid-prefill* — rows already written by
+                # earlier chunks must not be clobbered — so inactive
+                # slots get the max_len sentinel, whose one-hot write row
+                # is all zeros (no write). The paged path already drops
+                # idle writes via its write_mask.
+                step_index = self.cache_index
+                if not self.paged:
+                    step_index = jnp.where(
+                        jnp.asarray(active), self.cache_index, self.max_len
+                    )
+            with span("serve.decode.dispatch"):
+                step_stats = None
+                if self._telemetry and self.step_fn_t is not None:
+                    logits, self.cache, step_stats = self._dispatch(
+                        self.step_fn_t,
+                        self.params, self.cache, inputs, step_index,
+                    )
+                else:
+                    logits, self.cache = self._dispatch(
+                        self.step_fn,
+                        self.params, self.cache, inputs, step_index,
+                    )
+                self.cache_index = self.cache_index + jnp.asarray(
+                    active, jnp.int32)
+                self._lengths += active
+                if self._injector is not None:
+                    doomed = self._injector.poison_decode(
+                        [self.slots[i].uid for i in live]
+                    )
+                    if doomed:
+                        pmask = np.zeros((self.batch_slots,), bool)
+                        for i in live:
+                            if self.slots[i].uid in doomed:
+                                pmask[i] = True
+                        logits = _poison_logits(logits, jnp.asarray(pmask))
+                next_tokens, self.slot_keys, finite = _sample_step(
+                    logits, jnp.asarray(self._temps), self.slot_keys,
+                    inputs["active"],
+                )
+            if self.paged and self.sharing:
+                with span("serve.decode.register"):
+                    self._register_filled_pages(live)
+            with span("serve.decode.sync"):
+                if step_stats is not None:
+                    # stats ride the device_get the engine already pays
+                    # for the sampled tokens — no extra host sync on the
+                    # telemetry path.
+                    next_tokens, finite, stats_host = jax.device_get(
+                        (next_tokens, finite, step_stats)
+                    )
+                    self.obs.record_decode_stats(
+                        np.asarray(stats_host), slots=live)
+                else:
+                    next_tokens, finite = jax.device_get(
+                        (next_tokens, finite))
+            if self._injector is not None:
+                # injected straggler: the sleep lands inside decode_time
+                # so the StragglerMonitor sees it like a real slow step.
+                delay = self._injector.step_delay()
+                if delay:
+                    time.sleep(delay)
+            self.metrics.decode_dispatches += 1
+            elapsed = sp.emit("decode_tick", site="decode", live=len(live))
         self.metrics.decode_time += elapsed
-        self._emit("decode_tick", site="decode", dur=elapsed, live=len(live))
         if self.straggler.record(elapsed):
             self.metrics.stragglers += 1
+        return live, next_tokens, finite
+
+    def _register_filled_pages(self, live: List[int]):
+        """A decode append that just *filled* a page freezes it:
+        register its content (prompt + written generations) so a
+        preempted-and-resumed twin — or an identical re-request — can
+        attach instead of re-prefilling. Registered pages are
+        immutable; the slot's next append starts a new page. The
+        registration re-walks the slot's chain from the root — O(len)
+        host work per page fill, bounded by the engine's rows ≤ max_len
+        invariant (≤ max_len²/bk per request, dict lookups on small
+        tuples) — noise next to a decode dispatch."""
+        bk = self.layout.page_size
+        for i in live:
+            n = int(self._lengths[i])
+            if n and n % bk == 0:
+                req = self.slots[i]
+                self.allocator.register_prefix(
+                    i, req.prompt + req.tokens_out
+                )
+
+    def _commit_step(self, live: List[int], next_tokens, finite):
+        """Commit each live slot's sampled token (streaming it through
+        ``on_token``), quarantining a slot whose logits went
+        non-finite: only the faulted slot dies — its pages free under
+        the allocator's normal rules, its neighbours' committed tokens
+        are untouched."""
         for i in live:
             req = self.slots[i]
             if not bool(finite[i]):
-                # quarantine: only the faulted slot dies — its pages
-                # free under the allocator's normal rules, its
-                # neighbours' committed tokens are untouched.
                 self._evict_slot(i, "failed", "non-finite logits")
                 continue
             self.metrics.decode_tokens += 1
             self._commit_token(i, req, int(next_tokens[i]))
-        self._end_tick()
 
     # --- draining ------------------------------------------------------
     def _has_work(self) -> bool:
