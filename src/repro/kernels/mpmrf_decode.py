@@ -1,19 +1,28 @@
 """Fused MP-MRF decode kernels (Energon §IV-D, l = 1, on TPU).
 
-The serve-time hot path: one folded GQA query group against a long KV
+The serve-time hot path: folded GQA query groups against a long KV
 cache whose *filter operands are resident* — every cache page carries
 persistent int16 key codes and one scale (DESIGN.md §3), so the filter
 never re-quantizes. The kernels address the cache as pages through a
 block table; an unpaged cache is the special case of an identity table
 over its own rows (:mod:`repro.kernels.ops`). Two kernels:
 
-* :func:`mpmrf_paged_filter_scores` — grid ``(bh, mb)``: each step
-  streams one page's int16 codes, derives the two rounds' bit planes
-  *in-register* (arithmetic shifts — no plane materialization in HBM),
-  runs the Fig. 7 shift-and-add two-round scoring against the query's
-  hi-bit plane on the MXU (int8 operands, int32 accumulation), and
-  writes the two block maxima. The page's scale multiplies the maxima
-  afterwards. Bytes/step = the int16 codes, once.
+* :func:`mpmrf_paged_filter_scores` — grid ``(B, mb)``: step (b, j)
+  scores slot b's logical block j for *every* KV head. The pool's
+  ``[KV, rows, d]`` layout puts all heads' copies of one page at one row
+  offset, so a single ``(KV, bk, d)`` BlockSpec fetches them in one
+  strided DMA: bytes/step = ``KV · bk · d`` int16 codes (393 KB for
+  phi3-mini's 32 heads of 96 over 64-row pages), once; steps/call =
+  ``B · mb``. The step derives the two rounds' bit planes *in-register*
+  (arithmetic shifts — no plane materialization in HBM), scores them as
+  two int8 MXU matmuls of the heads' ``[KV·G, d]`` query planes against
+  their ``[KV·bk, d]`` key planes (int32 accumulation; the hi-bit
+  round's product equals Fig. 7's shift-and-add exactly), keeps each
+  query row's own head's lanes, and writes the two block maxima of
+  every row into lane j. A head's maximum over its G rows, then the
+  page's scale, are applied afterwards. Blocks past a slot's
+  length are neither fetched nor scored. An unpaged cache is the same
+  kernel over a slot axis of per-slot codes with an identity table.
 * :func:`paged_decode_gather_attention` — grid ``(bh, budget)``:
   block-gather flash attention over the survivor table. The K/V
   BlockSpec ``index_map`` composes the scalar-prefetched survivor ids
@@ -22,7 +31,7 @@ over its own rows (:mod:`repro.kernels.ops`). Two kernels:
   (On-Demand Fetching at serve time).
 
 Eq. 3 thresholds + exact-budget tier selection run between the two
-kernels in plain XLA: they touch ``[bh, mb]`` scalars — noise next to
+kernels in plain XLA: they touch ``[B·KV, mb]`` scalars — noise next to
 the cache streams — and reuse the exact selection rule of the XLA path
 (:func:`repro.core.filtering.decode_block_tier_select`), keeping fused
 and unfused decode bit-identical in selection.
@@ -40,6 +49,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128
+_INT_MIN = -(2 ** 31)
+#: VMEM for one head group's int32-widened codes inside a filter step.
+_GROUP_BYTES = 4 << 20
 
 
 def round_maxima(qp, codes, qs, ok, *, lo: int, hi: int):
@@ -88,29 +100,75 @@ def apply_page_scale(m, page_scale, bits: int):
     )
 
 
+def _heads_per_step(kv: int, g: int, block_k: int, d: int) -> int:
+    """Heads scored together inside one grid step: the largest divisor
+    of ``kv`` whose query rows fill at most one MXU tile (``heads · g ≤
+    128``) and whose int32-widened codes fit :data:`_GROUP_BYTES` of
+    VMEM."""
+    best = 1
+    for h in range(1, kv + 1):
+        if (kv % h == 0 and h * g <= _LANES
+                and h * block_k * d * 4 <= _GROUP_BYTES):
+            best = h
+    return best
+
+
 def _paged_filter_kernel(
     bt_ref, cl_ref,                       # scalar-prefetch operands
     qp_ref, qs_ref, kc_ref,
     m0_ref, m1_ref,
-    *, lo: int, hi: int, block_k: int,
+    *, lo: int, hi: int, block_k: int, g: int,
 ):
-    """Grid step (b, j) streams the *physical page* ``bt[b, j]`` holding
-    row b's logical block j — the BlockSpec index maps read the
-    scalar-prefetched block table, so the HBM→VMEM pipeline only ever
-    touches pages the table names."""
+    """Grid step (b, j) scores slot b's logical block j for every KV
+    head at once. The codes BlockSpec fetches ``[KV, bk, d]`` — each
+    head's copy of the *physical page* ``bt[b, j]``, one strided DMA.
+    Per group of ``hg`` heads the step runs two int8 MXU matmuls of the
+    group's ``[hg·g, d]`` query planes against its ``[hg·bk, d]`` key
+    planes and keeps, for each query row, the lanes of its own head:
+    the products are exact int32, so the other heads' lanes change
+    nothing. Round 1 scores the whole hi-bit key plane, whose product
+    equals Fig. 7's shift-and-add ``(acc0 << (hi − lo)) + q · rem``
+    exactly.
+
+    Maxima are taken on the integers first — ``max_k(f32(acc) · s) ==
+    f32(max_k acc) · s`` bit for bit for a query scale ``s > 0``, since
+    rounding and scaling are monotone — so each (head, query) row's
+    maximum equals :func:`round_maxima`'s. Blocks at or past the slot's
+    length are NEG_INF (set once at j = 0) and not computed; their index
+    map repeats the last live page, so the pipeline starts no copy."""
     b = pl.program_id(0)
     j = pl.program_id(1)
-    g = qp_ref.shape[0]
-    # positions are *logical*: block j's tokens, wherever they live
-    kpos = j * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (g, block_k), 1
-    )
-    m0, m1 = round_maxima(
-        qp_ref[...], kc_ref[...], qs_ref[...], kpos < cl_ref[b],
-        lo=lo, hi=hi,
-    )
-    store_lane(m0_ref, j, m0)
-    store_lane(m1_ref, j, m1)
+    n_grp, rows, _ = m0_ref.shape
+
+    @pl.when(j == 0)
+    def _init():
+        m0_ref[...] = jnp.full(m0_ref.shape, NEG_INF, jnp.float32)
+        m1_ref[...] = jnp.full(m1_ref.shape, NEG_INF, jnp.float32)
+
+    @pl.when(j * block_k < cl_ref[b])
+    def _score():
+        hg = rows // g
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, hg * block_k), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, hg * block_k), 0)
+        # positions are *logical*: block j's tokens, wherever they live
+        ok = jnp.logical_and(
+            lane // block_k == row // g,
+            j * block_k + lane % block_k < cl_ref[b],
+        )
+        dims = (((1,), (1,)), ((), ()))
+        for grp in range(n_grp):
+            codes = kc_ref[grp * hg:(grp + 1) * hg].astype(jnp.int32)
+            codes = codes.reshape(hg * block_k, codes.shape[-1])
+            qp = qp_ref[grp]                                  # [hg·g, d]
+            qs = qs_ref[grp] * float(2 ** (16 - hi))          # [hg·g, 1]
+            for bits, ref in ((lo, m0_ref), (hi, m1_ref)):
+                acc = jax.lax.dot_general(
+                    qp, jnp.right_shift(codes, 16 - bits).astype(jnp.int8),
+                    dims, preferred_element_type=jnp.int32,
+                )                                             # [hg·g, hg·bk]
+                t = jnp.max(jnp.where(ok, acc, _INT_MIN), axis=1,
+                            keepdims=True)
+                store_lane(ref.at[grp], j, t.astype(jnp.float32) * qs)
 
 
 @functools.partial(
@@ -120,7 +178,7 @@ def _paged_filter_kernel(
 def mpmrf_paged_filter_scores(
     q_plane: jax.Array,
     q_scale: jax.Array,
-    k_codes_pages: jax.Array,
+    k_codes: jax.Array,
     k_page_scale: jax.Array,
     block_table: jax.Array,
     cache_length: jax.Array,
@@ -129,69 +187,79 @@ def mpmrf_paged_filter_scores(
     key_block: int = 64,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Two-round block-max decode scores off the resident *page pool*.
+    """Two-round block-max decode scores off resident int16 codes.
 
     Args:
-      q_plane: int ``[bh, G, d]`` query hi-bit plane (fits int8).
-      q_scale: float32 ``[bh, G, 1]`` per-row quantization scales.
-      k_codes_pages: int16 ``[n_pages, bk, d]`` pool codes, page-major
-        (callers fold the KV-head axis into the page axis and offset
-        the table accordingly).
-      k_page_scale: float32 ``[n_pages, 1]`` per-page scales.
-      block_table: int32 ``[bh, mb]`` physical page of each logical
-        block (already head-offset). Unmapped blocks may alias any
-        in-range page — their logical positions are ≥ cache_length, so
-        every score they produce is NEG_INF-masked.
-      cache_length: int32 ``[bh]`` live logical lengths.
+      q_plane: int ``[B, KV·G, d]`` query hi-bit planes (fit int8), head
+        major: row ``h·G + i`` is query ``i`` of KV head ``h``.
+      q_scale: float32 ``[B, KV·G, 1]`` per-row quantization scales.
+      k_codes: int16 ``[S, KV, rows, d]`` codes, pages of ``key_block``
+        rows along ``rows``: ``S = 1`` for a shared page pool, or
+        ``S = B`` for one cache per slot (slot b reads ``k_codes[b]``).
+      k_page_scale: float32 ``[S, KV, rows / key_block]`` page scales.
+      block_table: int32 ``[B, mb]`` page of each logical block, the
+        same for every head. Blocks at or past ``cache_length`` may
+        alias any in-range page: they are never scored.
+      cache_length: int32 ``[B]`` live logical lengths.
 
     Returns:
-      ``(s0, s1)`` float32 ``[bh, mb]`` block-max scores per round.
+      ``(s0, s1)`` float32 ``[B, KV, mb]`` block-max scores per round.
     """
     lo, hi = round_bits
-    bh, g, d = q_plane.shape
+    batch, _, d = q_plane.shape
+    slots, kv = k_codes.shape[:2]
+    if slots not in (1, batch):
+        raise ValueError(f"{slots} code slots for a batch of {batch}")
+    g = q_plane.shape[1] // kv
     bk = key_block
     mb = block_table.shape[-1]
+    hg = _heads_per_step(kv, g, bk, d)
+    n_grp = kv // hg
     block_table = block_table.astype(jnp.int32)
 
-    kernel = functools.partial(
-        _paged_filter_kernel, lo=lo, hi=hi, block_k=bk
-    )
+    def codes_map(b, j, bt, cl):
+        last = jnp.maximum(cl[b] - 1, 0) // bk
+        return (b if slots > 1 else 0, 0, bt[b, jnp.minimum(j, last)], 0)
+
+    def per_slot(*block):
+        return pl.BlockSpec(
+            (None, n_grp) + block, lambda b, j, bt, cl: (b, 0, 0, 0)
+        )
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(bh, mb),
+        grid=(batch, mb),
         in_specs=[
-            pl.BlockSpec((None, g, d), lambda b, j, bt, cl: (b, 0, 0)),
-            pl.BlockSpec((None, g, 1), lambda b, j, bt, cl: (b, 0, 0)),
-            pl.BlockSpec(
-                (None, bk, d), lambda b, j, bt, cl: (bt[b, j], 0, 0)
-            ),
+            per_slot(hg * g, d),
+            per_slot(hg * g, 1),
+            pl.BlockSpec((None, kv, bk, d), codes_map),
         ],
-        out_specs=[
-            pl.BlockSpec((None, 1, mb), lambda b, j, bt, cl: (b, 0, 0)),
-            pl.BlockSpec((None, 1, mb), lambda b, j, bt, cl: (b, 0, 0)),
-        ],
+        out_specs=[per_slot(hg * g, mb), per_slot(hg * g, mb)],
     )
     m0, m1 = pl.pallas_call(
-        kernel,
+        functools.partial(
+            _paged_filter_kernel, lo=lo, hi=hi, block_k=bk, g=g
+        ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, 1, mb), jnp.float32),
-            jax.ShapeDtypeStruct((bh, 1, mb), jnp.float32),
-        ],
+            jax.ShapeDtypeStruct((batch, n_grp, hg * g, mb), jnp.float32)
+        ] * 2,
         interpret=interpret,
         name="mpmrf_decode_filter",
     )(
         block_table,
         cache_length.astype(jnp.int32),
-        q_plane.astype(jnp.int8),
-        q_scale.astype(jnp.float32),
-        k_codes_pages,
+        q_plane.astype(jnp.int8).reshape(batch, n_grp, hg * g, d),
+        q_scale.astype(jnp.float32).reshape(batch, n_grp, hg * g, 1),
+        k_codes,
     )
-    ks = k_page_scale.astype(jnp.float32)[:, 0][block_table]   # [bh, mb]
-    return (
-        apply_page_scale(m0[:, 0, :], ks, lo),
-        apply_page_scale(m1[:, 0, :], ks, hi),
+    # a head's maximum over its G query rows (exact)
+    m0 = jnp.max(m0.reshape(batch, kv, g, mb), axis=2)
+    m1 = jnp.max(m1.reshape(batch, kv, g, mb), axis=2)
+    ks = jnp.take_along_axis(                                 # [B, KV, mb]
+        k_page_scale.astype(jnp.float32), block_table[:, None, :], axis=-1
     )
+    return apply_page_scale(m0, ks, lo), apply_page_scale(m1, ks, hi)
 
 
 def _paged_gather_kernel(

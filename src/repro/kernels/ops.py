@@ -244,9 +244,9 @@ def fused_decode_attention(
         q,
         k_cache.reshape(n_pages, bk, d),
         v_cache.reshape(n_pages, bk, d),
-        k_codes.reshape(n_pages, bk, d),
-        k_block_scale.reshape(n_pages, 1),
         _identity_table(batch * heads, n_kb),
+        k_codes, k_block_scale,
+        jnp.broadcast_to(jnp.arange(n_kb, dtype=jnp.int32), (batch, n_kb)),
         cache_length, live_budget,
         round_bits=tuple(round_bits), alphas=tuple(alphas),
         key_block=bk, block_budget=block_budget, keep_all=keep_all,
@@ -455,40 +455,43 @@ def _paged_decode_core(
         q,
         k_pool.reshape(n_pages, bk, d),
         v_pool.reshape(n_pages, bk, d),
-        k_codes.reshape(n_pages, bk, d),
-        k_scale.reshape(n_pages, 1),
         _head_offset_table(block_table, heads, num_pages),
+        k_codes[None], k_scale[None], block_table,
         cache_length, live_budget, **kw,
     )
 
 
 def _decode_pipeline(
-    q, k_pages, v_pages, codes_pages, scale_pages, bt_bh, cache_length,
-    live_budget, *, round_bits, alphas, key_block, block_budget,
-    keep_all, keep_first, keep_diagonal, scale, interpret, with_stats,
+    q, k_pages, v_pages, bt_bh, codes, code_scale, block_table,
+    cache_length, live_budget, *, round_bits, alphas, key_block,
+    block_budget, keep_all, keep_first, keep_diagonal, scale, interpret,
+    with_stats,
 ):
-    """Filter kernel → shared selection → gather kernel over page-major
-    operands ``[n_pages, bk, d]`` addressed by the per-(batch, head)
-    table ``bt_bh [B·H, mb]``, under the named scopes ``select`` and
-    ``gather``. Returns ``(out, stats_or_None)``."""
+    """Filter kernel → shared selection → gather kernel, under the named
+    scopes ``select`` and ``gather``. The filter reads int16 ``codes
+    [S, KV, rows, d]`` and ``code_scale [S, KV, rows / bk]`` through the
+    per-slot ``block_table [B, mb]``, all heads of a slot's block in one
+    grid step; the gather reads page-major ``[n_pages, bk, d]`` K/V
+    through the per-(batch, head) table ``bt_bh [B·H, mb]``. Returns
+    ``(out, stats_or_None)``."""
     batch, heads, g, d = q.shape
     bh = batch * heads
 
     with jax.named_scope("select"):
         q16 = qlib.quantize_int16(q, axis=-1)
-        qp = q16.bit_plane(round_bits[-1]).reshape(bh, g, d)
-        qs = q16.scale.reshape(bh, g, 1)
+        qp = q16.bit_plane(round_bits[-1]).reshape(batch, heads * g, d)
+        qs = q16.scale.reshape(batch, heads * g, 1)
         cl_bh = jnp.repeat(cache_length.astype(jnp.int32), heads)
 
         s0, s1 = dec_kernel.mpmrf_paged_filter_scores(
-            qp, qs, codes_pages, scale_pages, bt_bh, cl_bh,
+            qp, qs, codes, code_scale, block_table, cache_length,
             round_bits=tuple(round_bits),
             key_block=key_block,
             interpret=interpret,
         )
 
         idx, val, stats = _fused_decode_select(
-            s0, s1, cl_bh,
+            s0.reshape(bh, -1), s1.reshape(bh, -1), cl_bh,
             alphas=alphas, key_block=key_block, block_budget=block_budget,
             keep_all=keep_all, keep_first=keep_first,
             keep_diagonal=keep_diagonal,

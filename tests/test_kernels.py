@@ -159,20 +159,27 @@ class TestFusedDecodeKernel:
         n = k.shape[-2]
         bh = B * H
         q16 = qlib.quantize_int16(q, axis=-1)
-        qp = q16.bit_plane(4).reshape(bh, G, d)
-        qs = q16.scale.reshape(bh, G, 1)
+        qp = q16.bit_plane(4)
+        qs = q16.scale
         cl_bh = jnp.repeat(cl, H)
-        pages, table = _identity_pages(codes.reshape(bh, n, d), bk)
+        # per-slot caches: S = B code slots, identity table per slot
+        table = jnp.tile(jnp.arange(n // bk, dtype=jnp.int32), (B, 1))
         s0, s1 = dk.mpmrf_paged_filter_scores(
-            qp, qs, pages, scales.reshape(bh * (n // bk), 1), table,
-            cl_bh, round_bits=(2, 4), key_block=bk, interpret=True,
+            qp.reshape(B, H * G, d), qs.reshape(B, H * G, 1), codes,
+            scales, table, cl, round_bits=(2, 4), key_block=bk,
+            interpret=True,
         )
         r0, r1 = ref.mpmrf_decode_filter_ref(
-            qp, qs, codes.reshape(bh, n, d), scales.reshape(bh, n // bk),
+            qp.reshape(bh, G, d), qs.reshape(bh, G, 1),
+            codes.reshape(bh, n, d), scales.reshape(bh, n // bk),
             cl_bh, round_bits=(2, 4), key_block=bk,
         )
-        np.testing.assert_allclose(np.asarray(s0), np.asarray(r0), rtol=1e-6)
-        np.testing.assert_allclose(np.asarray(s1), np.asarray(r1), rtol=1e-6)
+        np.testing.assert_allclose(
+            np.asarray(s0).reshape(bh, -1), np.asarray(r0), rtol=1e-6
+        )
+        np.testing.assert_allclose(
+            np.asarray(s1).reshape(bh, -1), np.asarray(r1), rtol=1e-6
+        )
 
     @pytest.mark.parametrize("ratio", [2.0, 4.0])
     def test_fused_matches_xla_decode_path(self, ratio):
@@ -371,29 +378,76 @@ class TestFusedDecodePaged:
         B, H, G, d = q.shape
         n = k.shape[-2]
         mb = n // bk
-        bh = B * H
-        num_pages = pool["scale"].shape[-1]
         q16 = qlib.quantize_int16(q, axis=-1)
-        qp = q16.bit_plane(4).reshape(bh, G, d)
-        qs = q16.scale.reshape(bh, G, 1)
-        cl_bh = jnp.repeat(cl, H)
-        pages, table = _identity_pages(codes.reshape(bh, n, d), bk)
+        qp = q16.bit_plane(4).reshape(B, H * G, d)
+        qs = q16.scale.reshape(B, H * G, 1)
+        identity = jnp.tile(jnp.arange(mb, dtype=jnp.int32), (B, 1))
         r0, r1 = dk.mpmrf_paged_filter_scores(
-            qp, qs, pages, scales.reshape(bh * mb, 1), table,
-            cl_bh, round_bits=(2, 4), key_block=bk, interpret=True,
+            qp, qs, codes, scales, identity, cl,
+            round_bits=(2, 4), key_block=bk, interpret=True,
         )
-        head_off = (jnp.arange(H, dtype=jnp.int32) * num_pages)
-        bt_bh = (
-            jnp.asarray(tables)[:, None, :] + head_off[None, :, None]
-        ).reshape(bh, mb)
         s0, s1 = dk.mpmrf_paged_filter_scores(
-            qp, qs,
-            pool["codes"].reshape(H * num_pages, bk, d),
-            pool["scale"].reshape(H * num_pages, 1),
-            bt_bh, cl_bh, round_bits=(2, 4), key_block=bk, interpret=True,
+            qp, qs, pool["codes"][None], pool["scale"][None],
+            jnp.asarray(tables), cl,
+            round_bits=(2, 4), key_block=bk, interpret=True,
         )
         np.testing.assert_array_equal(np.asarray(r0), np.asarray(s0))
         np.testing.assert_array_equal(np.asarray(r1), np.asarray(s1))
+
+    @pytest.mark.parametrize("name,kv,g,d", [
+        ("phi3-mini", 32, 1, 96), ("qwen3-14b-gqa", 8, 5, 128),
+    ])
+    def test_filter_scores_bit_identical_to_xla_scoring(self, name, kv, g, d):
+        """One grid step per (slot, page) for all KV heads, at the
+        serving widths: slots share prefix pages, lengths are ragged (an
+        idle slot, one ending mid-page, one full), and table entries past
+        a slot's length alias arbitrary pages — the scores equal the XLA
+        scoring path's bit for bit."""
+        from repro.core import filtering as flt
+        from repro.kernels import mpmrf_decode as dk
+        from repro.runtime import paged_cache as pgc
+
+        B, bk, mb, num_pages = 4, 64, 5, 14
+        rng = np.random.default_rng(kv)
+        codes = jnp.asarray(
+            rng.integers(-2 ** 15, 2 ** 15, size=(kv, num_pages * bk, d)),
+            jnp.int16,
+        )
+        scale = jnp.asarray(rng.uniform(1e-3, 1e-1, (kv, num_pages)),
+                            jnp.float32)
+        # pages 0-1 hold a prefix every slot shares; the rest are owned
+        tables = rng.integers(0, num_pages, size=(B, mb)).astype(np.int32)
+        tables[:, :2] = [0, 1]
+        tables[1, 2:] = [2, 3, 4]
+        tables[2, 2:] = [5, 6, 7]
+        tables[3, 2:4] = [8, 9]
+        lengths = jnp.asarray([0, mb * bk, 2 * bk + 17, 3 * bk], jnp.int32)
+        q16 = qlib.quantize_int16(_mk((B, kv, g, d), kv + 1), axis=-1)
+
+        s0, s1 = dk.mpmrf_paged_filter_scores(
+            q16.bit_plane(4).reshape(B, kv * g, d),
+            q16.scale.reshape(B, kv * g, 1),
+            codes[None], scale[None], jnp.asarray(tables), lengths,
+            round_bits=(2, 4), key_block=bk, interpret=True,
+        )
+
+        table = jnp.asarray(tables)
+        k16 = qlib.blockwise_quantized_view(
+            pgc.gather_logical_rows(codes, table, bk),
+            pgc.gather_logical_scales(scale, table), bk,
+        )
+        valid = (jnp.arange(mb * bk)[None, :] < lengths[:, None])
+        valid = jnp.broadcast_to(valid[:, None, None, :], (B, kv, g, mb * bk))
+        cfg = flt.MPMRFConfig(round_bits=(2, 4), alphas=(0.0, 0.0),
+                              granularity="block", key_block=bk)
+        xla = [
+            flt.pool_block_scores(sc, g, bk, valid)[0][:, :, 0, :]
+            for sc in flt._round_score_planes(q16, k16, cfg)
+        ]
+        np.testing.assert_array_equal(np.asarray(s0), np.asarray(xla[0]))
+        np.testing.assert_array_equal(np.asarray(s1), np.asarray(xla[1]))
+        assert np.all(np.asarray(s1)[0] == -1e30)          # the idle slot
+        assert np.all(np.asarray(s1)[2, :, 3:] == -1e30)   # past mid-page
 
     def test_paged_gather_vs_xla_paged_oracle(self):
         from repro.core import sparse_attention as spa

@@ -63,13 +63,13 @@ def _dims(kv, g):
 
 @pytest.mark.parametrize("name,kv,g,d", SHAPES)
 def test_decode_filter_kernel(one_chip, name, kv, g, d):
-    bh, pages = _dims(kv, g)
+    pages = SLOTS * MB
     text = _compile(
         one_chip,
         functools.partial(dk.mpmrf_paged_filter_scores, key_block=PAGE),
-        ((bh, g, d), jnp.int8), ((bh, g, 1), jnp.float32),
-        ((pages, PAGE, d), jnp.int16), ((pages, 1), jnp.float32),
-        ((bh, MB), jnp.int32), ((bh,), jnp.int32),
+        ((SLOTS, kv * g, d), jnp.int8), ((SLOTS, kv * g, 1), jnp.float32),
+        ((1, kv, pages * PAGE, d), jnp.int16), ((1, kv, pages), jnp.float32),
+        ((SLOTS, MB), jnp.int32), ((SLOTS,), jnp.int32),
     )
     assert "mpmrf_decode_filter" in text
 
