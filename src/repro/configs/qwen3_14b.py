@@ -1,5 +1,5 @@
 """qwen3-14b [dense]: 40L d_model=5120 40H (GQA kv=8) d_ff=17408
-vocab=151936 — qk_norm, GQA. [hf:Qwen/Qwen3-8B; hf]"""
+vocab=151936 — qk_norm, GQA. [hf:Qwen/Qwen3-14B; hf]"""
 
 import dataclasses
 

@@ -193,19 +193,25 @@ def energon_attention(
         elif n_k // cfg.key_block <= 1:
             impl = "mpmrf_row"
 
-    # Fused Pallas prefill: resident planes + per-row positions. This
+    # Prefill kernels: resident planes + per-row positions. This
     # short-circuits *before* the [n_q, n_k] mask/score materialization
     # (causality, sentinels and pooling all happen on-chip per tile),
     # which is also why the chunk_threshold guard below does not apply
-    # to it.
+    # to it. Layers below min_prune_layer stream dense attention over
+    # the cache's pages; the rest run the fused MP-MRF pipeline.
     if (
-        impl == "pallas" and q_positions is not None and causal
-        and _fused_prefill_engaged(
-            cfg, filter_cache is not None, window, kv_length, n_k
+        q_positions is not None and causal
+        and _prefill_kernels_engaged(
+            cfg, filter_cache is not None, window, kv_length, n_q, n_k
         )
     ):
         from repro.kernels import ops as kops
 
+        if layer_index < cfg.min_prune_layer:
+            return ret(kops.dense_prefill_attention(
+                q, k, v, q_positions, page=cfg.decode_key_block,
+                scale=scale,
+            ))
         n_kb = n_k // cfg.key_block
         return kops.fused_prefill_attention(
             q, k, v,
@@ -229,8 +235,8 @@ def energon_attention(
 
     # Above this size, materialized [n_q, n_k] scores/masks do not fit
     # HBM: switch to the scan-over-query-blocks (flash-style) paths.
-    # The q_positions (serve-prefill) form has no chunked variant, so
-    # enforce the guard instead of silently materializing past it.
+    # The XLA q_positions (serve-prefill) form has no chunked variant,
+    # so enforce the guard instead of silently materializing past it.
     if q_positions is not None and n_q * n_k > cfg.chunk_threshold:
         raise ValueError(
             f"q_positions attention materializes [{n_q}, {n_k}] masks "
@@ -531,6 +537,29 @@ def _fused_prefill_engaged(
     )
 
 
+def _prefill_kernels_engaged(
+    cfg: EnergonConfig,
+    filter_planes_resident: bool,
+    window: Optional[int],
+    kv_length: Optional[jax.Array],
+    n_q: int,
+    n_k: int,
+) -> bool:
+    """Where a chunked prefill runs on the Pallas prefill kernels, for
+    every layer: :func:`_fused_prefill_engaged` plus the block shapes
+    the fused pipeline tiles (whole query blocks, whole key tiles, more
+    than one of them). Pruned layers then take the fused MP-MRF
+    kernels, the layers below ``min_prune_layer`` the streamed dense
+    kernel; the unpaged and paged entry points share this predicate."""
+    return (
+        _fused_prefill_engaged(cfg, filter_planes_resident, window,
+                               kv_length, n_k)
+        and n_q % cfg.query_block == 0
+        and n_k % cfg.key_block == 0
+        and n_k // cfg.key_block > 1
+    )
+
+
 def energon_paged_prefill_attention(
     q: jax.Array,
     cache: Dict[str, jax.Array],
@@ -546,17 +575,26 @@ def energon_paged_prefill_attention(
     """Chunked-prefill attention straight against the page pool.
 
     The paged counterpart of the ``q_positions`` form of
-    :func:`energon_attention`. When the fused prefill kernels engage
-    (resident planes, key tiles of whole pages, block-divisible shapes),
-    the chunk attends the pool *in place*: the filter kernel scores the
-    per-page codes through the block table and the gather kernel's
-    BlockSpec index maps compose survivor table ∘ block table, so
-    unselected and unmapped pages never leave HBM. Otherwise the
-    per-slot logical K/V views are materialized transiently (zeroed
-    past each slot's written extent, exactly as before) and fed to
-    ``energon_attention`` — with the *gathered* resident planes as its
-    filter operands, so fused and fallback selection stay bit-identical
-    on the same pool contents.
+    :func:`energon_attention`. When the prefill kernels engage (impl
+    ``pallas``, resident planes, key tiles of whole pages,
+    block-divisible shapes), the chunk attends the pool *in place*:
+
+    * pruned layers: the filter kernel scores the per-page codes through
+      the block table and the gather kernel's BlockSpec index maps
+      compose survivor table ∘ block table, so unselected and unmapped
+      pages never leave HBM;
+    * layers below ``min_prune_layer``: the streamed dense kernel
+      (``paged_prefill_dense``, grid ``(slot, key block)``, all KV heads'
+      copies of a page in one DMA, the slot's query rows and
+      online-softmax state resident) reads each slot's pages up to its
+      written extent, so no logical view, score or mask tensor exists
+      and ``chunk_threshold`` does not bind.
+
+    Otherwise the per-slot logical K/V views are materialized
+    transiently (zeroed past each slot's written extent, exactly as
+    before) and fed to ``energon_attention`` — with the *gathered*
+    resident planes as its filter operands, so fused and fallback
+    selection stay bit-identical on the same pool contents.
 
     Args:
       q: ``[B, KV, n_q, d]`` folded GQA query rows.
@@ -575,17 +613,16 @@ def energon_paged_prefill_attention(
     n_k = mb * ps
     n_q = q.shape[-2]
 
-    fused = (
-        layer_index >= cfg.min_prune_layer
-        and _fused_prefill_engaged(cfg, "k_codes" in cache, window,
-                                   None, n_k)
-        and n_k % cfg.key_block == 0
-        and n_q % cfg.query_block == 0
-        and n_k // cfg.key_block > 1
-    )
-    if fused:
+    if _prefill_kernels_engaged(cfg, "k_codes" in cache, window, None,
+                                n_q, n_k):
         from repro.kernels import ops as kops
 
+        if layer_index < cfg.min_prune_layer:
+            out = kops.dense_paged_prefill_attention(
+                q, cache["k"], cache["v"], block_table, q_positions,
+                page=ps, scale=scale,
+            )
+            return (out, _zero_stats(q.shape[0])) if telemetry else out
         n_kb = n_k // cfg.key_block
         return kops.fused_paged_prefill_attention(
             q, cache["k"], cache["v"],

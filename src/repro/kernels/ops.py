@@ -838,6 +838,96 @@ def _prefill_pipeline(
     return out, None
 
 
+def dense_prefill_attention(
+    q: jax.Array,
+    k_cache: jax.Array,
+    v_cache: jax.Array,
+    q_positions: jax.Array,
+    *,
+    page: int = 64,
+    scale: Optional[float] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Dense (unpruned) chunked prefill over padded per-slot caches:
+    the streamed kernel of :func:`dense_paged_prefill_attention` with
+    each slot's ``[KV, n_k, d]`` cache as its own pool and an identity
+    table over its ``page``-row blocks.
+
+    Args:
+      q: ``[B, KV, n_q, d]`` folded chunk rows.
+      k_cache, v_cache: ``[B, KV, n_k, d]`` padded caches.
+      q_positions: int32 ``[B, n_q]`` absolute positions (sentinels
+        ≥ n_k).
+    """
+    interpret = _default_interpret() if interpret is None else interpret
+    batch, n_k = q.shape[0], k_cache.shape[-2]
+    table = jnp.broadcast_to(
+        jnp.arange(n_k // page, dtype=jnp.int32), (batch, n_k // page)
+    )
+    with jax.named_scope("dense"):
+        return pre_kernel.paged_prefill_dense_attention(
+            q, q_positions, k_cache, v_cache, table,
+            key_block=page, scale=scale, interpret=interpret,
+        )
+
+
+def dense_paged_prefill_attention(
+    q: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    block_table: jax.Array,
+    q_positions: jax.Array,
+    *,
+    page: int = 64,
+    scale: Optional[float] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Dense (unpruned) chunked prefill straight off the page pool.
+
+    The layers below ``min_prune_layer`` attend every key: the kernel
+    (:func:`repro.kernels.mpmrf_prefill.paged_prefill_dense_attention`,
+    grid ``(B, mb)``) streams each slot's pages through the block table
+    — all KV heads' copies of a page in one DMA — with the slot's
+    folded query rows and online-softmax state resident, so no logical
+    K/V view and no ``[n_q, n_k]`` scores exist in HBM. Runs under the
+    named scope ``dense``; under an active serve mesh it shard-maps over
+    KV heads exactly as :func:`fused_paged_prefill_attention`.
+
+    Args:
+      q: ``[B, KV, n_q, d]`` folded chunk rows.
+      k_pool, v_pool: ``[KV, pool_rows, d]`` shared page pools.
+      block_table: int32 ``[B, max_blocks]`` logical → physical pages.
+      q_positions: int32 ``[B, n_q]`` absolute positions per row.
+
+    Returns:
+      ``[B, KV, n_q, d]`` attention output (dtype of v_pool).
+    """
+    interpret = _default_interpret() if interpret is None else interpret
+
+    def core(q, k_pool, v_pool, block_table, q_positions):
+        return pre_kernel.paged_prefill_dense_attention(
+            q, q_positions, k_pool[None], v_pool[None], block_table,
+            key_block=page, scale=scale, interpret=interpret,
+        )
+
+    args = (q, k_pool, v_pool, block_table, q_positions)
+    with jax.named_scope("dense"):
+        mesh = _tp_mesh(q.shape[1])
+        if mesh is None:
+            return core(*args)
+        pool = P("model", None, None)
+
+        def body(*xs):
+            return jax.lax.all_gather(core(*xs), "model", axis=1, tiled=True)
+
+        return jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(P(None, "model", None, None), pool, pool,
+                      P(None, None), P(None, None)),
+            out_specs=P(), check_vma=False,
+        )(*args)
+
+
 @functools.partial(
     jax.custom_vjp,
     nondiff_argnums=(3, 4, 5, 6),

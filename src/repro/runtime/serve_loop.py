@@ -502,6 +502,18 @@ def _sample_step(
     return _sample_wave(logits[:, -1, :], temps, keys, active)
 
 
+def _chunk_span(pos: np.ndarray, sentinel: int) -> span:
+    """``serve.prefill.chunk`` around one chunk wave's dispatch. Its args
+    name each live slot's first position and live query rows, in slot
+    order and space-separated (a comma would end the profiler's
+    argument), e.g. ``starts="16384 16610"``, ``rows="128 37"``."""
+    rows = (pos < sentinel).sum(axis=1)
+    live = np.flatnonzero(rows)
+    return span("serve.prefill.chunk",
+                starts=" ".join(str(int(pos[i, 0])) for i in live),
+                rows=" ".join(str(int(rows[i])) for i in live))
+
+
 @jax.jit
 def _poison_logits(logits: jax.Array, mask: jax.Array) -> jax.Array:
     """Chaos hook: replace ``mask`` slots' logits with NaN (fault
@@ -1366,17 +1378,20 @@ class ServeLoop:
                 }
                 if bt is not None:
                     inputs["block_table"] = bt
-                if use_t:
-                    logits, self.cache, st = self._dispatch(
-                        self.prefill_fn_t,
-                        self.params, self.cache, inputs, self.cache_index,
-                    )
-                    stats_chunks.append(st)
-                else:
-                    logits, self.cache = self._dispatch(
-                        self.prefill_fn,
-                        self.params, self.cache, inputs, self.cache_index,
-                    )
+                with _chunk_span(pos, self.max_len):
+                    if use_t:
+                        logits, self.cache, st = self._dispatch(
+                            self.prefill_fn_t,
+                            self.params, self.cache, inputs,
+                            self.cache_index,
+                        )
+                        stats_chunks.append(st)
+                    else:
+                        logits, self.cache = self._dispatch(
+                            self.prefill_fn,
+                            self.params, self.cache, inputs,
+                            self.cache_index,
+                        )
                 self.metrics.prefill_dispatches += 1
                 self._emit("prefill_chunk", site="prefill",
                            chunk=c, slots=len(admitted))
@@ -1503,16 +1518,17 @@ class ServeLoop:
                 inputs["block_table"] = bt
             use_t = self._telemetry and self.prefill_fn_t is not None
             stats = None
-            if use_t:
-                logits, self.cache, stats = self._dispatch(
-                    self.prefill_fn_t,
-                    self.params, self.cache, inputs, self.cache_index,
-                )
-            else:
-                logits, self.cache = self._dispatch(
-                    self.prefill_fn,
-                    self.params, self.cache, inputs, self.cache_index,
-                )
+            with _chunk_span(pos, self.max_len):
+                if use_t:
+                    logits, self.cache, stats = self._dispatch(
+                        self.prefill_fn_t,
+                        self.params, self.cache, inputs, self.cache_index,
+                    )
+                else:
+                    logits, self.cache = self._dispatch(
+                        self.prefill_fn,
+                        self.params, self.cache, inputs, self.cache_index,
+                    )
             self.metrics.prefill_dispatches += 1
             self._emit("prefill_chunk", site="prefill",
                        chunk=min((j.pos - j.skip) // C for _, j in jobs),
@@ -1740,7 +1756,8 @@ class ServeLoop:
         is in decode state by the time the decode step dispatches.
 
         Each phase is a `span` nested under ``serve.tick`` (DESIGN.md
-        §8): ``serve.admit``, ``serve.prefill`` (with
+        §8): ``serve.admit``, ``serve.prefill`` (with a
+        ``serve.prefill.chunk`` around each chunk wave's dispatch, and
         ``serve.prefill.sync``), ``serve.decode`` (``.inputs``,
         ``.dispatch``, ``.register``, ``.sync``), ``serve.commit`` and
         ``serve.end``."""

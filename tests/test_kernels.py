@@ -792,6 +792,65 @@ class TestFusedPrefillKernel:
             np.asarray(out_p), np.asarray(out_x), atol=1e-5
         )
 
+    @pytest.mark.parametrize("dead", ["tail_block", "idle_slot"])
+    def test_dead_query_blocks_read_as_masked(self, dead):
+        """Query blocks with no live row are skipped by the filter and
+        gather grids; they must still read what a masked block reads
+        (NEG_INF planes, zero rows), and the live blocks beside them
+        must be unchanged — against the jnp oracle and the XLA path."""
+        from repro.core import EnergonConfig, energon_attention
+        from repro.kernels import mpmrf_prefill as pk
+
+        q, k, v, qpos, codes, scales, bq, bk = self._setup(
+            seed=11, ragged=12 if dead == "tail_block" else 4
+        )
+        B, H, n_q, d = q.shape
+        n_k = k.shape[-2]
+        if dead == "idle_slot":
+            qpos = qpos.at[0].set(n_k)
+        bh = B * H
+        live = np.asarray(qpos).reshape(B, n_q // bq, bq).min(-1) < n_k
+        assert not live.all() and live.any()
+
+        q16 = qlib.quantize_int16(q, axis=-1)
+        qp = q16.bit_plane(4).reshape(bh, n_q, d)
+        qs = q16.scale.reshape(bh, n_q, 1)
+        qpos_bh = jnp.repeat(qpos, H, axis=0)
+        pages, table = _identity_pages(codes.reshape(bh, n_k, d), bk)
+        s0, s1 = pk.mpmrf_paged_prefill_filter_scores(
+            qp, qs, qpos_bh, pages, scales.reshape(bh * (n_k // bk), 1),
+            table, round_bits=(2, 4), query_block=bq, key_block=bk,
+            interpret=True,
+        )
+        r0, r1 = ref.mpmrf_prefill_filter_ref(
+            qp, qs, qpos_bh, codes.reshape(bh, n_k, d),
+            jnp.repeat(scales, bk, axis=-1).reshape(bh, n_k),
+            round_bits=(2, 4), query_block=bq, key_block=bk,
+        )
+        np.testing.assert_allclose(np.asarray(s0), np.asarray(r0),
+                                   rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(s1), np.asarray(r1),
+                                   rtol=1e-6)
+        dead_bh = ~np.repeat(live, H, axis=0)
+        assert (np.asarray(s1)[dead_bh] <= pk.NEG_INF / 2).all()
+
+        fc = {"codes": codes, "scale": scales}
+        kw = dict(pruning_ratio=2.0, query_block=bq, key_block=bk,
+                  decode_key_block=bk, min_prune_layer=0)
+        out_x = energon_attention(
+            q, k, v, EnergonConfig(impl="mpmrf_block", **kw),
+            q_positions=qpos, layer_index=5, filter_cache=fc,
+        )
+        out_p = energon_attention(
+            q, k, v, EnergonConfig(impl="pallas", **kw),
+            q_positions=qpos, layer_index=5, filter_cache=fc,
+        )
+        np.testing.assert_allclose(
+            np.asarray(out_p), np.asarray(out_x), atol=1e-5
+        )
+        rows_dead = np.repeat(~live, bq, axis=1)          # [B, n_q]
+        assert (np.asarray(out_p).transpose(0, 2, 1, 3)[rows_dead] == 0).all()
+
     def test_key_tile_of_two_scale_blocks_matches_xla(self, monkeypatch):
         """Unpaged cache whose key tiles span two resident scale blocks:
         the fused path pools block pairs and must select exactly as the
@@ -1114,3 +1173,153 @@ class TestFusedPrefillPaged:
             jnp.asarray(tables), qpos, **kw
         )
         np.testing.assert_array_equal(np.asarray(ref_out), np.asarray(out))
+
+
+class TestPagedPrefillDense:
+    """The dense layers' streamed prefill (``paged_prefill_dense``): one
+    grid step per (slot, page) for all KV heads, online softmax over
+    the slot's pages read in place. It must equal the materialized
+    dense ``q_positions`` path to f32 rounding on the same pool."""
+
+    def _setup(self, kv, g, *, C=8, d=16, bk=8, mb=6, num_pages=20,
+               seed=0):
+        """Four slots over one pool: slots 0 and 1 share their first two
+        pages (a prefix); slot 0 writes up to a mid-page extent, slot 1
+        has no live row (sentinels only), slot 2 ends mid-page after a
+        ragged tail, slot 3 reaches max_len; entries past each slot's
+        extent alias arbitrary pages, other slots' live ones included."""
+        rng = np.random.default_rng(seed)
+        n = mb * bk
+        B = 4
+        tables = rng.integers(0, num_pages, size=(B, mb)).astype(np.int32)
+        tables[:2, :2] = [3, 7]
+        tables[0, 2:4] = [11, 2]
+        tables[2, :3] = [5, 9, 12]
+        tables[3] = [13, 14, 15, 16, 17, 18]
+        pos = np.full((B, C), n, np.int32)
+        pos[0] = 21 + np.arange(C)                   # extent 29: mid-page
+        pos[2, :5] = 14 + np.arange(5)               # ragged tail, extent 19
+        pos[3] = n - C + np.arange(C)                # the last row is n - 1
+        qpos = jnp.asarray(np.tile(pos, (1, g)))     # folded (g, c) rows
+        q = _mk((B, kv, g * C, d), seed + 1)
+        k_pool = _mk((kv, num_pages * bk, d), seed + 2)
+        v_pool = _mk((kv, num_pages * bk, d), seed + 3)
+        return q, k_pool, v_pool, jnp.asarray(tables), qpos, bk
+
+    @pytest.mark.parametrize("kv,g", [(4, 1), (2, 5)], ids=["G1", "G5"])
+    def test_matches_materialized_dense_path(self, kv, g):
+        from repro.core import EnergonConfig, energon_paged_prefill_attention
+
+        q, k_pool, v_pool, table, qpos, bk = self._setup(kv, g)
+        out = ops.dense_paged_prefill_attention(
+            q, k_pool, v_pool, table, qpos, page=bk, interpret=True
+        )
+        # the XLA fallback: zeroed logical views, [n_q, n_k] scores
+        cfg = EnergonConfig(impl="mpmrf_block", decode_key_block=bk,
+                            min_prune_layer=2)
+        ref = energon_paged_prefill_attention(
+            q, {"k": k_pool, "v": v_pool}, table, qpos, cfg, layer_index=0
+        )
+        n = table.shape[-1] * bk
+        live = np.broadcast_to(np.asarray(qpos < n)[:, None, :, None],
+                               out.shape)
+        np.testing.assert_allclose(np.asarray(out)[live],
+                                   np.asarray(ref)[live],
+                                   rtol=1e-5, atol=1e-6)
+        assert np.all(np.asarray(out)[~live] == 0)    # sentinels read 0
+        assert np.all(np.asarray(out)[1] == 0)        # the idle slot
+
+    @pytest.mark.parametrize("kv,g", [(4, 1), (2, 5)], ids=["G1", "G5"])
+    def test_paged_dispatch_and_unpaged_cache(self, kv, g, monkeypatch):
+        """``impl="pallas"`` with resident planes sends a dense layer's
+        chunk to the kernel, and the unpaged cache (each slot's own
+        cache, identity table) gives the paged result bit for bit."""
+        from repro.core import (
+            EnergonConfig,
+            energon_attention,
+            energon_paged_prefill_attention,
+        )
+        from repro.runtime import paged_cache as pgc
+
+        q, k_pool, v_pool, table, qpos, bk = self._setup(kv, g, seed=3)
+        n = table.shape[-1] * bk
+        codes, scale = qlib.quantize_int16_blocks(k_pool, bk)
+        cache = {"k": k_pool, "v": v_pool, "k_codes": codes,
+                 "k_scale": scale[..., 0]}
+        calls = []
+        real = ops.dense_paged_prefill_attention
+        monkeypatch.setattr(
+            ops, "dense_paged_prefill_attention",
+            lambda *a, **kw: calls.append(kw["page"]) or real(*a, **kw),
+        )
+        cfg = EnergonConfig(impl="pallas", query_block=8, key_block=2 * bk,
+                            decode_key_block=bk, min_prune_layer=2,
+                            filter_cache_min_len=0)
+        paged = energon_paged_prefill_attention(
+            q, cache, table, qpos, cfg, layer_index=1
+        )
+        assert calls == [bk]
+        extent = jnp.max(jnp.where(qpos < n, qpos + 1, 0), axis=1)
+        row_ok = (jnp.arange(n)[None, :] < extent[:, None])[:, None, :, None]
+        k_log = pgc.gather_logical_rows(k_pool, table, bk) * row_ok
+        v_log = pgc.gather_logical_rows(v_pool, table, bk) * row_ok
+        c_log, s_log = qlib.quantize_int16_blocks(k_log, bk)
+        unpaged = energon_attention(
+            q, k_log, v_log, cfg, layer_index=1, q_positions=qpos,
+            filter_cache={"codes": c_log, "scale": s_log[..., 0]},
+        )
+        np.testing.assert_array_equal(np.asarray(paged), np.asarray(unpaged))
+
+    def test_threshold_no_longer_binds_served_prefill(self):
+        """A tiny Qwen3-shaped model (QK-norm, G = 5, 2 dense + 2 pruned
+        layers, impl "pallas") serves the same prefill-then-decode
+        logits with ``chunk_threshold`` below its dense layers'
+        ``G·C·max_len`` as with the default: neither the dense nor the
+        fused prefill consults it."""
+        from repro.configs.base import ModelConfig
+        from repro.core import EnergonConfig
+        from repro.models import LMModel
+
+        B, C, max_len, bk = 2, 16, 128, 16
+        g = 5
+
+        def serve(threshold):
+            kw = {} if threshold is None else {"chunk_threshold": threshold}
+            cfg = ModelConfig(
+                name="qwen3-tiny", family="dense", num_layers=4,
+                d_model=40, num_heads=2 * g, num_kv_heads=2, head_dim=8,
+                d_ff=64, vocab_size=64, use_qk_norm=True,
+                dtype="float32", remat="none",
+                energon=EnergonConfig(
+                    impl="pallas", pruning_ratio=2.0, query_block=16,
+                    key_block=2 * bk, decode_key_block=bk,
+                    min_prune_layer=2, filter_cache_min_len=0, **kw),
+            )
+            model = LMModel(cfg)
+            params = model.init(jax.random.PRNGKey(0))
+            mb = max_len // bk
+            cache = model.init_paged_cache(B * mb, max_len=max_len)
+            table = jnp.arange(B * mb, dtype=jnp.int32).reshape(B, mb)
+            toks = jax.random.randint(jax.random.PRNGKey(1), (B, C), 1, 63)
+            lens = np.array([C, 11])
+            pos = np.where(np.arange(C)[None, :] < lens[:, None],
+                           np.arange(C)[None, :], max_len)
+            logits, cache = jax.jit(model.prefill)(
+                params, cache,
+                {"tokens": toks, "positions": jnp.asarray(pos, jnp.int32),
+                 "block_table": table},
+                jnp.zeros((B,), jnp.int32),
+            )
+            step, _ = jax.jit(model.decode_step)(
+                params, cache,
+                {"tokens": toks[:, :1], "active": jnp.ones((B,), bool),
+                 "block_table": table},
+                jnp.asarray(lens, jnp.int32),
+            )
+            return np.asarray(logits), np.asarray(step)
+
+        assert g * C * max_len > 1024
+        low, default = serve(1024), serve(None)
+        np.testing.assert_array_equal(low[0], default[0])
+        np.testing.assert_array_equal(low[1], default[1])
+        assert np.all(np.isfinite(low[1]))

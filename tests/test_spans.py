@@ -125,6 +125,41 @@ class TestEngineSpans:
         assert spans["serve.prefill"][0][2]["uids"] == "[7]"
         assert engine.slots[0].tokens_out    # first token and one decode
 
+    def test_chunk_spans_carry_their_wave(self, mt, tmp_path):
+        """Each chunk wave's dispatch is a ``serve.prefill.chunk`` inside
+        ``serve.prefill``, whose args give the live slots' first
+        positions and live query rows, space-separated."""
+        from jax.profiler import ProfileData
+
+        engine = _engine(mt)
+        engine.submit(Request(uid=1, prompt=list(range(1, 12)),
+                              max_new_tokens=2))
+        engine.submit(Request(uid=2, prompt=[5, 6, 7, 8, 9],
+                              max_new_tokens=2))
+        warm = _engine(mt)                       # compile outside the trace
+        warm.submit(Request(uid=0, prompt=[1, 2, 3], max_new_tokens=4))
+        warm.tick()
+        with jax.profiler.trace(str(tmp_path)):
+            engine.tick()
+            engine.tick()
+        path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True)[-1]
+        spans = {"serve.prefill": [], "serve.prefill.chunk": []}
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in spans:
+                        spans[ev.name].append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns,
+                             {k: str(v) for k, v in ev.stats}))
+        chunks = sorted(spans["serve.prefill.chunk"], key=lambda s: s[0])
+        waves = spans["serve.prefill"]
+        assert len(chunks) == len(waves) == 2
+        for c0, c1, _ in chunks:
+            assert any(p0 <= c0 <= c1 <= p1 for p0, p1, _ in waves)
+        assert chunks[0][2] == {"starts": "0 0", "rows": "8 5"}
+        assert chunks[1][2] == {"starts": "8", "rows": "3"}
+
     def test_events_and_counters_share_the_span_clock(self, mt):
         """``decode_tick`` / ``prefill_tick`` durations are the very
         readings ``decode_time`` and ``prefill_time`` add up."""
@@ -183,4 +218,18 @@ class TestNamedScopes:
             assert any(scope in n for n in names), scope
         # the jitted filter kernel is called inside the select scope
         assert any(n.startswith("attention/select/jit(mpmrf_paged_")
+                   for n in names)
+
+    def test_prefill_program_streams_dense_layers_under_their_scope(
+            self, mt):
+        """The layer below ``min_prune_layer`` attends through the
+        streamed dense kernel, called inside ``attention/dense``."""
+        engine = _engine(mt)
+        B = engine.batch_slots
+        inputs = {"tokens": jnp.zeros((B, 8), jnp.int32),
+                  "positions": jnp.zeros((B, 8), jnp.int32),
+                  "block_table": engine._device_block_table()}
+        names = _scope_names(engine.prefill_fn.lower(
+            engine.params, engine.cache, inputs, engine.cache_index))
+        assert any(n.startswith("attention/dense/jit(paged_prefill_dense")
                    for n in names)

@@ -163,3 +163,34 @@ def test_fused_paged_prefill_pipeline(one_chip, name, kv, g, d):
         ((SLOTS, CHUNK * g), jnp.int32),
     )
     assert "mpmrf_prefill_filter" in text and "mpmrf_prefill_gather" in text
+
+
+@pytest.mark.parametrize("name,kv,g,d", SHAPES)
+def test_prefill_dense_kernel(one_chip, name, kv, g, d):
+    """The dense layers' streamed prefill: one chunk's folded rows of
+    every slot over the shared pool, through the block table."""
+    rows = SLOTS * MB * PAGE
+    text = _compile(
+        one_chip,
+        functools.partial(pk.paged_prefill_dense_attention, key_block=PAGE),
+        ((SLOTS, kv, CHUNK * g, d), jnp.bfloat16),
+        ((SLOTS, CHUNK * g), jnp.int32),
+        ((1, kv, rows, d), jnp.bfloat16), ((1, kv, rows, d), jnp.bfloat16),
+        ((SLOTS, MB), jnp.int32),
+    )
+    assert "paged_prefill_dense" in text
+
+
+@pytest.mark.parametrize("name,kv,g,d", SHAPES)
+def test_dense_paged_prefill_dispatch(one_chip, name, kv, g, d):
+    """The dense prefill as the serving engine's dense layers call it,
+    on the pool leaves a paged cache holds."""
+    k, v, _, _, table = _pool(kv, d)
+    text = _compile(
+        one_chip,
+        functools.partial(ops.dense_paged_prefill_attention, page=PAGE,
+                          interpret=False),
+        ((SLOTS, kv, CHUNK * g, d), jnp.bfloat16), k, v, table,
+        ((SLOTS, CHUNK * g), jnp.int32),
+    )
+    assert "paged_prefill_dense" in text
